@@ -1,8 +1,5 @@
 # Tier-1: the correctness gate — must stay NO WORSE than the seed
-# baseline. (The two seed-era failures — tests/test_pipeline.py and
-# tests/test_dryrun_machinery.py tripping over `jax.lax.axis_size` /
-# list-valued `cost_analysis()` API drift — were fixed in PR 8; the
-# whole suite is expected green.)
+# baseline (the pass count of the last accepted run).
 # Tier-2: cheap perf smoke for PRs touching the hot paths — refreshes
 # benchmarks/out/BENCH_portfolio.json on a tiny matrix in <60s.
 

@@ -6,18 +6,19 @@ Feeds the ``sharded`` section of ``benchmarks/out/BENCH_portfolio.json``
 * ``device_sweep`` — the combined grid launch
   (``schedule_portfolio_grid(..., devices=d)``) timed at each device
   count over the SAME instance rows, bitwise-verified against the
-  single-device launch.  The sweep runs in a subprocess so
+  single-device launch.  On an accelerator the sweep runs in-process
+  over the real devices (a chip belongs to one process, so a child
+  could not reach it).  On CPU it runs in a subprocess so
   ``--xla_force_host_platform_device_count`` lands before the jax
-  backend initializes; on this container every "device" is a slice of
-  the same host CPU (``host_cpus`` is recorded next to the curve), so
-  the numbers measure partitioning overhead, not parallel speedup —
-  wall-clock scaling needs real accelerators, and the curve is recorded
-  as measured rather than extrapolated.
-* ``gain_kernel`` — the tiled Pallas ``gain_scan`` vs its jnp
-  prefix-sum twin across task counts.  On CPU the kernel executes under
-  the Pallas interpreter (orders of magnitude slower than compiled
-  jnp), so ``crossover_n`` is honestly ``null`` here; the compiled
-  TPU/GPU lowering is where the tile layout pays.
+  backend initializes; every "device" is then a slice of the same host
+  CPU (``host_cpus`` is recorded next to the curve), so the numbers
+  measure partitioning overhead, not parallel speedup.
+* ``gain_kernel`` — the tiled Pallas gain kernel vs its jnp prefix-sum
+  twin across task counts, both over the same gathered windows.  On a
+  chip the kernel is the compiled one (``kernel_mode: "pallas"``); on
+  CPU it executes under the Pallas interpreter (``"interpret"``, orders
+  of magnitude slower than compiled jnp), so ``crossover_n`` is
+  ``null`` there.
 """
 from __future__ import annotations
 
@@ -51,10 +52,9 @@ def _build_rows(n_inst: int):
     return plat, insts, rows
 
 
-def _child_sweep(devices: list[int], n_inst: int, reps: int) -> dict:
-    """Runs INSIDE the forced-device-count subprocess: time the grid
-    launch per device count and prove bitwise identity against the
-    single-device baseline."""
+def _sweep(devices: list[int], n_inst: int, reps: int) -> dict:
+    """Time the grid launch per device count over the visible devices and
+    prove bitwise identity against the single-device baseline."""
     import jax
 
     from repro.core.portfolio import schedule_portfolio_grid
@@ -96,14 +96,21 @@ def _child_sweep(devices: list[int], n_inst: int, reps: int) -> dict:
         "variants": list(_SWEEP_VARIANTS),
         "curve": curve,
         "note": ("virtual host devices share one CPU: the curve measures "
-                 "shard_map partitioning overhead on this box, not "
-                 "parallel speedup"),
+                 "shard_map partitioning overhead, not parallel speedup")
+        if jax.default_backend() == "cpu" else "real devices",
     }
 
 
 def device_sweep(devices=(1, 2, 8), n_inst: int = 8, reps: int = 3) -> dict:
-    """Run :func:`_child_sweep` in a subprocess with the forced host
-    device count, so the parent's already-initialized backend is moot."""
+    """Run :func:`_sweep` over the real devices in-process on an
+    accelerator (counts past the visible devices are dropped), or in a
+    subprocess with the forced host device count on CPU, so the parent's
+    already-initialized backend is moot."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        n = len(jax.devices())
+        return _sweep([d for d in devices if d <= n], n_inst, reps)
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count="
@@ -123,13 +130,21 @@ def device_sweep(devices=(1, 2, 8), n_inst: int = 8, reps: int = 3) -> dict:
 
 def gain_kernel_crossover(sizes=(256, 1024), t: int = 512, mu: int = 21,
                           reps: int = 3) -> dict:
-    """jnp prefix-sum twin vs the (interpreted-on-CPU) Pallas kernel."""
+    """jnp prefix-sum twin vs the Pallas gain kernel (compiled on a chip,
+    interpreted on CPU) over the same gathered windows."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.gain_scan import gain_scan
+    from repro.kernels.gain_scan import _kernel_call, gains_from_windows, \
+        gather_windows
 
     backend = jax.default_backend()
+    kernel_mode = "interpret" if backend == "cpu" else "pallas"
+    twin = jax.jit(functools.partial(gains_from_windows, mu=mu))
+    kernel = jax.jit(functools.partial(_kernel_call, mu=mu,
+                                       mode=kernel_mode))
     points = []
     for n in sizes:
         rng = np.random.default_rng(n)
@@ -137,33 +152,30 @@ def gain_kernel_crossover(sizes=(256, 1024), t: int = 512, mu: int = 21,
         dur = jnp.asarray(rng.integers(1, 9, n).astype(np.float32))
         start = jnp.asarray(rng.integers(0, t - 10, n).astype(np.float32))
         work = jnp.asarray(rng.integers(0, 7, n).astype(np.float32))
-        lo = jnp.maximum(start - 30, 0)
-        hi = start + 30
+        win_s, win_e = gather_windows(rem, start, dur, mu=mu)
+        lo_rel = jnp.maximum(start - 30, 0) - start
+        args = (win_s, win_e, work, dur, lo_rel, jnp.full_like(start, 30))
 
-        def timed(interpret):
-            gain_scan(rem, start, dur, work, lo, hi, mu=mu,
-                      interpret=interpret).block_until_ready()   # warm
+        def timed(fn):
+            fn(*args).block_until_ready()                       # warm
             ts = []
             for _ in range(reps):
                 t0 = time.perf_counter()
-                gain_scan(rem, start, dur, work, lo, hi, mu=mu,
-                          interpret=interpret).block_until_ready()
+                fn(*args).block_until_ready()
                 ts.append(time.perf_counter() - t0)
             return float(np.median(ts)) * 1e6
 
-        # interpret=None auto-dispatches: the jnp twin on CPU (this box)
-        points.append({"n_tasks": n, "t": t,
-                       "jnp_twin_us": timed(None),
-                       "kernel_us": timed(True)})
+        points.append({"n_tasks": n, "t": t, "jnp_twin_us": timed(twin),
+                       "kernel_us": timed(kernel)})
     faster = [p["n_tasks"] for p in points
               if p["kernel_us"] < p["jnp_twin_us"]]
     return {
         "backend": backend,
         "mu": mu,
-        "kernel_mode": "interpret" if backend == "cpu" else "pallas",
+        "kernel_mode": kernel_mode,
         "points": points,
         # smallest N where the kernel wins; null on CPU, where the
-        # interpreter (not the Mosaic/Triton lowering) runs the kernel
+        # interpreter (not the Mosaic lowering) runs the kernel
         "crossover_n": min(faster) if faster else None,
     }
 
@@ -187,7 +199,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
     if args.child:
-        out = _child_sweep([int(d) for d in args.devices.split(",")],
+        out = _sweep([int(d) for d in args.devices.split(",")],
                            args.n_inst, args.reps)
         print(json.dumps(out))
     else:

@@ -33,9 +33,7 @@ hit the same compiled executable; the jit cache is effectively keyed on the
 bucket tuple. Padding is output-invariant: padded tasks have zero
 duration/work and place at t=0 (a candidate point on every profile), padded
 time units are never feasible starts (mask False, and every real LST is
-below the real horizon), and the big per-call buffers (budget timeline,
-candidate masks) are donated to the runtime off-CPU so repeat calls reuse
-device memory.
+below the real horizon).
 
 Two longest-path representations serve the scan, chosen by
 :func:`repro.kernels.backend.resolve_lp_form` against an ``lp_budget_bytes``
@@ -328,28 +326,19 @@ def _build_fns():
     return greedy_scan, fanout, multi
 
 
-def _donate():
-    import jax
-    # donate the big per-call buffers (budget timeline, masks) so repeat
-    # calls reuse device memory; on CPU donation is a no-op and only warns,
-    # so it is enabled off-CPU only.
-    return (3, 4) if jax.default_backend() != "cpu" else ()
-
-
 @functools.lru_cache(maxsize=1)
 def _impl():
+    # no buffer donation: the only output (start times) matches no input's
+    # shape, so nothing could alias a donated budget or mask buffer
     import jax
 
     greedy_scan, fanout, multi = _build_fns()
-    don = _donate()
     return {
-        "single": jax.jit(greedy_scan, donate_argnums=don),
-        "fanout": jax.jit(fanout, donate_argnums=don),
-        "multi": jax.jit(multi, donate_argnums=don),
-        "batch": jax.jit(jax.vmap(fanout, in_axes=(0,) * 8),
-                         donate_argnums=don),
-        "grid": jax.jit(jax.vmap(multi, in_axes=(0,) * 8),
-                        donate_argnums=don),
+        "single": jax.jit(greedy_scan),
+        "fanout": jax.jit(fanout),
+        "multi": jax.jit(multi),
+        "batch": jax.jit(jax.vmap(fanout, in_axes=(0,) * 8)),
+        "grid": jax.jit(jax.vmap(multi, in_axes=(0,) * 8)),
     }
 
 
@@ -364,11 +353,10 @@ def _grid_sharded_impl(ndev: int):
     greedy scans with zero cross-device communication, and the result is
     bitwise-identical to the single-device grid (rows are independent and
     the per-row closure is literally the same traced function).
-    ``check_rep=False``: no replicated outputs to verify, and the scan
-    body trips the conservative replication checker.
+    ``check_vma=False``: no replicated outputs to verify, and the scan
+    body trips the conservative varying-axes checker.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
 
     from repro.sharding.ctx import grid_mesh
     from repro.sharding.specs import grid_batch_spec
@@ -376,9 +364,10 @@ def _grid_sharded_impl(ndev: int):
     _, _, multi = _build_fns()
     grid = jax.vmap(multi, in_axes=(0,) * 8)
     spec = grid_batch_spec()
-    sharded = shard_map(grid, mesh=grid_mesh(ndev), in_specs=(spec,) * 8,
-                        out_specs=spec, check_rep=False)
-    return jax.jit(sharded, donate_argnums=_donate())
+    sharded = jax.shard_map(grid, mesh=grid_mesh(ndev),
+                            in_specs=(spec,) * 8, out_specs=spec,
+                            check_vma=False)
+    return jax.jit(sharded)
 
 
 def _grid_launch(stacked, devices):
@@ -432,7 +421,8 @@ def _blocked_impl():
     fanout = jax.vmap(chunk_scan, in_axes=variant_axes)
     multi = jax.vmap(fanout, in_axes=profile_axes)
     # donate the state buffers so chained chunk launches reuse device
-    # memory (no-op + warning on CPU, so off-CPU only, as in _impl)
+    # memory (the state comes back with the same shapes; on CPU donation
+    # is a no-op that only warns, so it is enabled off-CPU only)
     don = tuple(range(2, 7)) if jax.default_backend() != "cpu" else ()
     return {
         "fanout": jax.jit(fanout, donate_argnums=don),

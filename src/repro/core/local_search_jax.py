@@ -10,11 +10,11 @@ gain order with exact integer re-evaluation. Two generations live here:
 * :func:`local_search_portfolio` / :func:`local_search_portfolio_multi` —
   the portfolio engine's climber: ALL rows (``-LS`` variants x ensemble
   profiles) advance together, and the whole gain/commit round loop runs
-  device-resident as ONE jitted ``lax.while_loop`` (gains via the jnp
-  prefix-sum twin of the Pallas kernel, commits as an in-loop top-K scan
-  with exact integer re-evaluation) — one host sync per hill climb, not
-  one per round. Rows carry per-variant round budgets and deactivate
-  individually when a round commits nothing.
+  device-resident as ONE jitted ``lax.while_loop`` (gains via the
+  compiled Pallas kernel on TPU, its jnp prefix-sum twin on CPU; commits
+  as an in-loop top-K scan with exact integer re-evaluation) — one host
+  sync per hill climb, not one per round. Rows carry per-variant round
+  budgets and deactivate individually when a round commits nothing.
 
 After the device climb converges, every row is *polished* with the exact
 sequential reference (:func:`repro.core.local_search.reference_round`)
@@ -319,8 +319,10 @@ def local_search_portfolio_multi(inst: Instance, T: int,
     Args:
       unit_budgets: int [R, T] per-row effective budget timelines.
       starts:       int [R, N] one greedy schedule per row.
-      interpret:    unused (the device loop's gain oracle is always the
-        jnp prefix-sum twin); kept for climber-signature compatibility.
+      interpret:    unused: the device loop's gain oracle is picked by
+        backend (:func:`repro.kernels.gain_scan.gains_windows_auto` — the
+        jnp prefix-sum twin on CPU, the compiled Pallas kernel on TPU);
+        kept for climber-signature compatibility.
       ctx:          optional shared graph context (``ls_graph_context``;
         extra keys such as ``unit_budget`` are ignored).
       commit_k:     device commits per row per round (None = the module

@@ -7,6 +7,9 @@ to Mosaic with no caller changes.
 """
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 
 def resolve_interpret(interpret: bool | None) -> bool:
     """Resolve the tri-state ``interpret`` flag against the active backend.
@@ -89,26 +92,33 @@ def resolve_engine(engine: str | None, fanout: int = 1) -> str:
     return engine
 
 
-def enable_compilation_cache(path: str | None = None) -> str | None:
-    """Persist compiled executables across processes (best effort).
+# The persistent compile cache's directory when JAX_COMPILATION_CACHE_DIR
+# is unset: one fixed path inside the checkout (listed in .gitignore). The
+# path is part of the cache key, so it must not move between processes.
+CHECKOUT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def compilation_cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else
+    :data:`CHECKOUT_CACHE_DIR`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or CHECKOUT_CACHE_DIR
+
+
+def enable_compilation_cache() -> str:
+    """Persist compiled executables across processes.
 
     The in-process jit cache already reuses executables across calls (the
     fan-out pads its inputs to shape buckets precisely so distinct
     instances hit it); this extends the reuse across process restarts —
     benchmark re-runs and replanning daemons skip the cold compile.
-    Returns the cache dir, or None when the jax version refuses.
+    Returns the cache dir (:func:`compilation_cache_dir`); raises
+    ``OSError`` when it cannot be created.
     """
-    import os
+    import jax
 
-    if path is None:
-        path = os.path.join(os.path.expanduser("~"), ".cache",
-                            "repro-jax-cache")
-    try:
-        import jax
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        return path
-    except Exception:
-        return None
+    path = compilation_cache_dir()
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path

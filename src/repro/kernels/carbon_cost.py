@@ -38,8 +38,10 @@ def _kernel(starts_ref, ends_ref, works_ref, g_ref, t0_ref, out_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # time coordinates of this tile: t0 + tile*TIME_TILE + [0..TIME_TILE)
+    # Mosaic only lowers integer iotas: build the offsets in int32, cast
     t = (t0_ref[0] + tile * TIME_TILE
-         + jax.lax.broadcasted_iota(jnp.float32, (1, TIME_TILE), 1))
+         + jax.lax.broadcasted_iota(jnp.int32, (1, TIME_TILE), 1)
+         .astype(jnp.float32))
     s = starts_ref[...]            # (1, TASK_CHUNK)
     e = ends_ref[...]
     w = works_ref[...]

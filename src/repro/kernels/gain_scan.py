@@ -82,7 +82,9 @@ def _gain_kernel(mu: int, win_s_ref, win_e_ref, w_ref, dur_ref, lo_ref,
     dur = dur_ref[...]
     lo = lo_ref[...]
     hi = hi_ref[...]
-    j = jax.lax.broadcasted_iota(jnp.float32, (1, W), 1)
+    # Mosaic only lowers integer iotas; the window offsets are compared
+    # against f32 shift bounds, so cast once
+    j = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1).astype(jnp.float32)
     lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
 
     released_s = jnp.minimum(jnp.maximum(-win_s, 0.0), w)
@@ -123,14 +125,23 @@ def _gain_kernel(mu: int, win_s_ref, win_e_ref, w_ref, dur_ref, lo_ref,
 
 
 def gather_windows(rem, start, dur, *, mu: int):
-    """(win_s, win_e) f32[N, W] timeline windows around start and end."""
+    """(win_s, win_e) f32[N, W] timeline windows around start and end:
+    ``win_s[i, j] = rem[start_i - mu + j]`` (0 outside the timeline), for
+    starts and ends within ``[0, T]``.
+
+    Row ``r`` of the sliding-window matrix holds ``rem_pad[r:r + W]``, so
+    each window is one whole-row gather: an element-wise gather of
+    ``N * W`` scalars runs at about 43M elements/s on a TPU v5e, slow
+    enough to dominate the climb.
+    """
     t_total = rem.shape[0]
     rem_pad = jnp.pad(rem, (W, W))
-    idx = jnp.arange(W)[None, :] - mu
+    top = t_total + W
+    rows = jnp.stack([rem_pad[j:j + top + 1] for j in range(W)], axis=1)
     s_i = start.astype(jnp.int32)
     e_i = (start + dur).astype(jnp.int32)
-    win_s = rem_pad[jnp.clip(s_i[:, None] + idx + W, 0, t_total + 2 * W - 1)]
-    win_e = rem_pad[jnp.clip(e_i[:, None] + idx + W, 0, t_total + 2 * W - 1)]
+    win_s = rows[jnp.clip(s_i + W - mu, 0, top)]
+    win_e = rows[jnp.clip(e_i + W - mu, 0, top)]
     return win_s, win_e
 
 
@@ -210,7 +221,7 @@ def _kernel_call(win_s, win_e, work, dur, lo_rel, hi_rel, *, mu: int,
     if mode == "pallas":
         # candidate tiles are independent: let Mosaic parallelize the grid
         from jax.experimental.pallas import tpu as pltpu
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel",))
     out = pl.pallas_call(
         functools.partial(_gain_kernel, mu),

@@ -9,12 +9,16 @@ state; only launch/dryrun.py sets the 512-host-device XLA flag.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model code places activations with
+    # with_sharding_constraint, which refuses Explicit mesh axes
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

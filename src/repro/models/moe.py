@@ -171,20 +171,19 @@ def moe_ffn_shardmap(p, x, moe_cfg):
     import math
     ds = math.prod(mesh.shape[a] for a in batch_axes)
     assert nt % ds == 0
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def inner(xs, gate, w1, w3, w2):
         # xs [ntl_local, d]; w* lead with E/tp local experts
         return _moe_shardmap_body(xs, gate, w1, w3, w2, moe_cfg, tp)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(batch_axes, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=P(batch_axes, None),
-        check_rep=False)
+        check_vma=False)
     out = fn(x.reshape(nt, d), p["gate"].astype(jnp.float32),
              p["w1"].astype(x.dtype), p["w3"].astype(x.dtype),
              p["w2"].astype(x.dtype))

@@ -12,6 +12,7 @@ import dataclasses
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 @dataclasses.dataclass
@@ -37,4 +38,5 @@ def rebuild_mesh(plan: ElasticPlan, devices=None):
     need = int(np.prod(plan.mesh_shape))
     assert len(devices) >= need, (len(devices), need)
     return jax.make_mesh(plan.mesh_shape, plan.axis_names,
-                         devices=devices[:need])
+                         devices=devices[:need],
+                         axis_types=(AxisType.Auto,) * len(plan.axis_names))

@@ -91,6 +91,7 @@ import heapq
 import itertools
 import threading
 import time
+import warnings
 
 import numpy as np
 
@@ -433,8 +434,11 @@ class PlanService:
         if compilation_cache:
             try:
                 self.compile_cache_dir = enable_compilation_cache()
-            except Exception:
-                self.compile_cache_dir = None
+            except OSError as e:
+                # the cache only saves compile time: serve without it,
+                # but say so (compile_cache_dir stays None)
+                warnings.warn(f"persistent compilation cache not enabled: "
+                              f"{e}", RuntimeWarning, stacklevel=2)
         self._planners: dict[tuple[str, bool], Planner] = {}
         self._planners_lock = threading.Lock()
         # EMA of observed per-candidate mapping-search seconds, feeding

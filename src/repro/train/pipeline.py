@@ -69,12 +69,10 @@ def pipeline_apply(body_fn, stage_params, x_mb, *, axis_name: str = "pod"):
 
 def make_pipelined_forward(body_fn, mesh, axis_name: str = "pod"):
     """Wrap pipeline_apply in shard_map for the given mesh."""
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         functools.partial(pipeline_apply, body_fn, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )

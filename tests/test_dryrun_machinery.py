@@ -11,7 +11,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
 import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCHS, reduced
 from repro.configs.base import ShapeConfig
@@ -22,7 +22,8 @@ from repro.sharding.specs import batch_specs, cache_specs, tree_param_specs
 from repro.train.optimizer import adamw_init
 from repro.train.step import make_train_step
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 configure(mesh)
 cfg = dataclasses.replace(reduced(ARCHS["qwen2.5-3b"]), num_heads=4,
                           kv_heads=2)

@@ -68,6 +68,27 @@ def test_gain_scan_sweep(n, t, mu):
     np.testing.assert_allclose(got[legal], want[legal], atol=1e-3)
 
 
+@pytest.mark.parametrize("n,t,mu", [(5, 16, 1), (300, 512, 10),
+                                    (64, 777, 42)])
+def test_gather_windows_matches_elementwise_definition(n, t, mu):
+    from repro.kernels.gain_scan import W, gather_windows
+
+    rng = np.random.default_rng(n + t + mu)
+    rem = rng.integers(-9, 9, t).astype(np.float32)
+    dur = rng.integers(1, 9, n)
+    start = rng.integers(0, t - dur + 1)
+    start[:2], dur[:2] = (0, t - 3), (1, 3)    # both horizon edges
+    win_s, win_e = gather_windows(jnp.asarray(rem),
+                                  jnp.asarray(start, jnp.int32),
+                                  jnp.asarray(dur, jnp.int32), mu=mu)
+    rem_pad = np.pad(rem, (W, W))
+    j = np.arange(W)[None, :] - mu + W
+    np.testing.assert_array_equal(np.asarray(win_s),
+                                  rem_pad[start[:, None] + j])
+    np.testing.assert_array_equal(np.asarray(win_e),
+                                  rem_pad[(start + dur)[:, None] + j])
+
+
 def test_kernel_cost_matches_core_oracle():
     plat = make_cluster(1, seed=2)
     wf = make_workflow("eager", 5, seed=4)

@@ -7,6 +7,7 @@ quarantine bisects) live in tests/test_chaos.py behind the ``chaos``
 marker (`make test-chaos`); this file keeps the acceptance-critical
 behaviours in the default tier-1 gate.
 """
+import os
 import time
 
 import numpy as np
@@ -440,6 +441,18 @@ def test_service_enables_compilation_cache_with_opt_out():
         assert svc.compile_cache_dir is None  # explicit opt-out
 
 
+def test_compilation_cache_dir_env_wins_else_fixed_checkout_path(
+        monkeypatch, tmp_path):
+    from repro.kernels import backend
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert backend.compilation_cache_dir() == os.path.join(root,
+                                                           ".jax_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert backend.compilation_cache_dir() == str(tmp_path)
+
+
 _WARM_RESTART_SCRIPT = """
 from repro.api import Planner, PlanRequest
 from repro.cluster import make_cluster
@@ -468,11 +481,10 @@ def test_service_restart_reuses_persistent_compilation_cache(tmp_path):
     populates the persistent jax compilation cache the startup hook
     enables; an identical second process adds no new entries (every
     compile is a cache hit)."""
-    import os
     import subprocess
     import sys
 
-    env = dict(os.environ, HOME=str(tmp_path),
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
                PYTHONPATH=os.pathsep.join(sys.path))
     cache_dir = None
     counts = []
@@ -485,7 +497,7 @@ def test_service_restart_reuses_persistent_compilation_cache(tmp_path):
                 if ln.startswith("CACHE_DIR=")][0]
         cache_dir = line[len("CACHE_DIR="):]
         counts.append(len(os.listdir(cache_dir)))
-    assert cache_dir.startswith(str(tmp_path))
+    assert cache_dir == str(tmp_path)
     assert counts[0] > 0, "cold run persisted no compiled executables"
     assert counts[1] == counts[0], \
         f"warm restart recompiled: {counts[0]} -> {counts[1]} entries"
