@@ -351,50 +351,54 @@ def local_search_portfolio_multi(inst: Instance, T: int,
     if adjacency not in (None, "dense", "padded"):
         raise ValueError(f"unknown adjacency form {adjacency!r}")
     padded = adjacency == "padded"
-    starts = np.asarray(starts, dtype=np.int64).copy()
-    R, N = starts.shape
-    unit_budgets = np.asarray(unit_budgets, dtype=np.int64)
-    ctx = ctx if ctx is not None else ls_graph_context(inst)
+    # host-side climb inputs: per-row remaining budget, bucket padding and
+    # the adjacency form, up to the device launch
+    with obs.span("ls_prep", padded=padded) as prep_span:
+        starts = np.asarray(starts, dtype=np.int64).copy()
+        R, N = starts.shape
+        prep_span.set(rows=int(R), N=int(N))
+        unit_budgets = np.asarray(unit_budgets, dtype=np.int64)
+        ctx = ctx if ctx is not None else ls_graph_context(inst)
 
-    rems = unit_budgets - np.stack(
-        [work_timeline(inst, T, starts[i]) for i in range(R)])
+        rems = unit_budgets - np.stack(
+            [work_timeline(inst, T, starts[i]) for i in range(R)])
 
-    # bucket-padded device inputs: padded tasks have work 0 (never legal),
-    # padded rows repeat row 0 (computed, discarded), padded time units are
-    # unreachable (moves clamp to the real horizon t_real)
-    Np = _bucket_up(N, N_BUCKET)
-    Tp = _bucket_up(T, T_BUCKET)
-    Rp = _bucket_up(R, 8)
-    rem_p = np.zeros((Rp, Tp), dtype=np.int32)
-    rem_p[:R, :T] = rems
-    rem_p[R:] = rem_p[0]
-    start_p = np.zeros((Rp, Np), dtype=np.int32)
-    start_p[:R, :N] = starts
-    start_p[R:] = start_p[0]
-    dur_p = np.zeros(Np, dtype=np.int32)
-    dur_p[:N] = inst.dur
-    work_p = np.zeros(Np, dtype=np.int32)
-    work_p[:N] = inst.task_work
-    if padded:
-        pidx, pok, sidx, sok = _padded_adjacency(inst, ctx)
-        D = pidx.shape[1]
-        pidx_p = np.zeros((Np, D), dtype=np.int32)
-        pidx_p[:N] = pidx
-        pok_p = np.zeros((Np, D), dtype=bool)
-        pok_p[:N] = pok
-        sidx_p = np.zeros((Np, D), dtype=np.int32)
-        sidx_p[:N] = sidx
-        sok_p = np.zeros((Np, D), dtype=bool)
-        sok_p[:N] = sok
-        adj_args = ((jnp.asarray(pidx_p), jnp.asarray(pok_p)),
-                    (jnp.asarray(sidx_p), jnp.asarray(sok_p)))
-    else:
-        pred, succ = _dense_adjacency(inst, ctx)
-        pred_p = np.zeros((Np, Np), dtype=bool)
-        pred_p[:N, :N] = pred
-        succ_p = np.zeros((Np, Np), dtype=bool)
-        succ_p[:N, :N] = succ
-        adj_args = (jnp.asarray(pred_p), jnp.asarray(succ_p))
+        # bucket-padded device inputs: padded tasks have work 0 (never
+        # legal), padded rows repeat row 0 (computed, discarded), padded
+        # time units are unreachable (moves clamp to the real horizon)
+        Np = _bucket_up(N, N_BUCKET)
+        Tp = _bucket_up(T, T_BUCKET)
+        Rp = _bucket_up(R, 8)
+        rem_p = np.zeros((Rp, Tp), dtype=np.int32)
+        rem_p[:R, :T] = rems
+        rem_p[R:] = rem_p[0]
+        start_p = np.zeros((Rp, Np), dtype=np.int32)
+        start_p[:R, :N] = starts
+        start_p[R:] = start_p[0]
+        dur_p = np.zeros(Np, dtype=np.int32)
+        dur_p[:N] = inst.dur
+        work_p = np.zeros(Np, dtype=np.int32)
+        work_p[:N] = inst.task_work
+        if padded:
+            pidx, pok, sidx, sok = _padded_adjacency(inst, ctx)
+            D = pidx.shape[1]
+            pidx_p = np.zeros((Np, D), dtype=np.int32)
+            pidx_p[:N] = pidx
+            pok_p = np.zeros((Np, D), dtype=bool)
+            pok_p[:N] = pok
+            sidx_p = np.zeros((Np, D), dtype=np.int32)
+            sidx_p[:N] = sidx
+            sok_p = np.zeros((Np, D), dtype=bool)
+            sok_p[:N] = sok
+            adj_args = ((jnp.asarray(pidx_p), jnp.asarray(pok_p)),
+                        (jnp.asarray(sidx_p), jnp.asarray(sok_p)))
+        else:
+            pred, succ = _dense_adjacency(inst, ctx)
+            pred_p = np.zeros((Np, Np), dtype=bool)
+            pred_p[:N, :N] = pred
+            succ_p = np.zeros((Np, Np), dtype=bool)
+            succ_p[:N, :N] = succ
+            adj_args = (jnp.asarray(pred_p), jnp.asarray(succ_p))
 
     checkpoint(cancel)                   # last rung before the device climb
     ck = _COMMIT_K if commit_k is None else int(commit_k)

@@ -307,7 +307,7 @@ def _assemble(names, prep: PreparedInstance, greedy: dict, ls_done: dict,
               cancel=None) -> dict[str, ScheduleResult]:
     """Finish a portfolio pass: -LS fallbacks, validation, costs."""
     checkpoint(cancel)    # per-cell rung (numpy -LS climbs run below)
-    out: dict[str, ScheduleResult] = {}
+    done: dict[str, tuple] = {}
     for name in names:
         if name == "asap":
             t0 = time.perf_counter()
@@ -326,12 +326,16 @@ def _assemble(names, prep: PreparedInstance, greedy: dict, ls_done: dict,
                                          prep.platform, start, mu=mu,
                                          ctx=prep.ls)
                     secs += time.perf_counter() - t0
-        if validate:
-            validate_schedule(prep.inst, prep.profile, start)
-        out[name] = ScheduleResult(
-            variant=name, start=start,
-            cost=schedule_cost(prep.inst, prep.profile, start), seconds=secs)
-    return out
+        done[name] = (start, secs)
+    if validate:
+        with obs.span("validate", variants=len(done)):
+            for start, _ in done.values():
+                validate_schedule(prep.inst, prep.profile, start)
+    return {name: ScheduleResult(
+                variant=name, start=start,
+                cost=schedule_cost(prep.inst, prep.profile, start),
+                seconds=secs)
+            for name, (start, secs) in done.items()}
 
 
 def schedule_portfolio_grid(instances, profile_grid, platform: Platform,
@@ -450,10 +454,11 @@ def schedule_portfolio_grid(instances, profile_grid, platform: Platform,
     # variants use (an asap-only request skips masks/segments entirely)
     rvals = tuple(sorted({r for (_, _, r) in need}))
     overlays: list = []
-    for i, (g, ps) in enumerate(zip(graphs, profile_grid)):
-        overlays.append(
-            overlays[dup_of[i]] if dup_of[i] != i else
-            [overlay_profile(g, p, refined_values=rvals) for p in ps])
+    with obs.span("overlays", rows=I * P, refined=rvals):
+        for i, (g, ps) in enumerate(zip(graphs, profile_grid)):
+            overlays.append(
+                overlays[dup_of[i]] if dup_of[i] != i else
+                [overlay_profile(g, p, refined_values=rvals) for p in ps])
     if heur and not all(g.feasible for g in graphs):
         raise ValueError("infeasible: deadline below ASAP makespan")
 
@@ -480,9 +485,10 @@ def schedule_portfolio_grid(instances, profile_grid, platform: Platform,
         for (Npad, Tp), idx in buckets.items():
             checkpoint(cancel)           # per-bucket-launch rung
             t0 = time.perf_counter()
+            n_rows = len(idx) * P * len(need)
             launch_span = obs.start_span(
                 "bucket_launch", bucket=f"{Npad}x{Tp}",
-                instances=len(idx), rows=len(idx) * P * len(need))
+                instances=len(idx), rows=n_rows)
             misses0 = _jit_entries_total()
             # duplicate rows reuse the unique row's host-built tuple (the
             # launch keeps its bucket shape; only row prep is skipped —
@@ -490,22 +496,23 @@ def schedule_portfolio_grid(instances, profile_grid, platform: Platform,
             # bucket, so it was built earlier in this idx walk)
             row_cache: dict[int, tuple] = {}
             rows = []
-            for i in idx:
-                if dup_of[i] in row_cache:
+            with obs.span("bucket_rows", parent=launch_span, rows=n_rows):
+                for i in idx:
+                    if dup_of[i] in row_cache:
+                        rows.append(row_cache[dup_of[i]])
+                        continue
+                    g = graphs[i]
+                    dur, work, lp, est_j, lst_j, tail = g.shared()
+                    budgets = pad_budget(np.stack(
+                        [ov.unit_budget for ov in overlays[i]]), Tp)
+                    masks = pad_masks(np.stack(
+                        [np.stack([ov.masks[r] for (_, _, r) in need])
+                         for ov in overlays[i]]), Tp)
+                    orders = pad_orders(np.stack(
+                        [g.order_for(s, w) for (s, w, _) in need]), tail)
+                    row_cache[dup_of[i]] = (dur, work, lp, budgets, masks,
+                                            est_j, lst_j, orders)
                     rows.append(row_cache[dup_of[i]])
-                    continue
-                g = graphs[i]
-                dur, work, lp, est_j, lst_j, tail = g.shared()
-                budgets = pad_budget(np.stack(
-                    [ov.unit_budget for ov in overlays[i]]), Tp)
-                masks = pad_masks(np.stack(
-                    [np.stack([ov.masks[r] for (_, _, r) in need])
-                     for ov in overlays[i]]), Tp)
-                orders = pad_orders(np.stack(
-                    [g.order_for(s, w) for (s, w, _) in need]), tail)
-                row_cache[dup_of[i]] = (dur, work, lp, budgets, masks,
-                                        est_j, lst_j, orders)
-                rows.append(row_cache[dup_of[i]])
             try:
                 starts = np.asarray(
                     greedy_fanout_grid_jax(rows, devices=devices),
@@ -520,7 +527,7 @@ def schedule_portfolio_grid(instances, profile_grid, platform: Platform,
                         labels=("bucket",)).inc(misses,
                                                 bucket=f"{Npad}x{Tp}")
                 launch_span.end(cache_misses=misses)
-            dt = (time.perf_counter() - t0) / (len(idx) * P * len(need))
+            dt = (time.perf_counter() - t0) / n_rows
             for b, i in enumerate(idx):
                 N = instances[i].num_tasks
                 for p in range(P):
@@ -577,17 +584,18 @@ def schedule_portfolio_grid(instances, profile_grid, platform: Platform,
         "grid cells served by the portfolio pass, by engine",
         labels=("engine",)).inc(I * P, engine=engine)
     out_rows: list = []
-    for i in range(I):
-        if dup_of[i] != i:
-            out_rows.append(out_rows[dup_of[i]])
-            continue
-        out_rows.append(
-            [_assemble(names,
-                       PreparedInstance(graph=graphs[i],
-                                        overlay=overlays[i][p]),
-                       greedys[i][p], ls_dones[i][p], mu, validate,
-                       cancel=cancel)
-             for p in range(P)])
+    with obs.span("assemble", cells=I * P):
+        for i in range(I):
+            if dup_of[i] != i:
+                out_rows.append(out_rows[dup_of[i]])
+                continue
+            out_rows.append(
+                [_assemble(names,
+                           PreparedInstance(graph=graphs[i],
+                                            overlay=overlays[i][p]),
+                           greedys[i][p], ls_dones[i][p], mu, validate,
+                           cancel=cancel)
+                 for p in range(P)])
     return out_rows
 
 

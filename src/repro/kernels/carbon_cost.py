@@ -95,5 +95,6 @@ def deficit_timeline(starts, ends, works, g_eff, *,
         out_shape=jax.ShapeDtypeStruct((1, g.shape[1]), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, TIME_TILE), jnp.float32)],
         interpret=interpret,
+        name="carbon_cost",
     )(starts, ends, works, g, t0)
     return out.reshape(-1)[:T]

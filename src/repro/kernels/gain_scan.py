@@ -237,6 +237,7 @@ def _kernel_call(win_s, win_e, work, dur, lo_rel, hi_rel, *, mu: int,
         out_specs=pl.BlockSpec((TASK_TILE, W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + n_pad, W), jnp.float32),
         interpret=(mode == "interpret"),
+        name="gain_scan",
         **kwargs,
     )(win_s, win_e, w2, dur2, lo2, hi2)
     return out[:n, :2 * mu + 1]

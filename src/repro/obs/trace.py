@@ -12,6 +12,16 @@ variable (``with tracer.span("child"):`` nests under the enclosing
 span) and explicit across threads: pass ``parent=`` or re-anchor a
 worker thread with ``with tracer.attach(span):``.
 
+Profiler mirroring: while a :class:`Tracer` is installed, every span is
+also a ``jax.profiler.TraceAnnotation`` (a TraceMe) under its own name,
+entered when the span starts and exited when it ends, so a collecting
+JAX profiler (``jax.profiler.trace(...)``) shows the spans on its host
+plane, on the same clock as the device's ops. With no profiler
+collecting a TraceMe records nothing. A span ended on another thread
+than the one that started it is recorded on the ending thread's line,
+with its true start and duration. jax is imported lazily, when a tracer
+is built; without jax the spans are simply not mirrored.
+
 Hot-path contract: when tracing is disabled the module-level facade in
 ``repro.obs`` returns the singleton :data:`NULL_SPAN`, whose every
 method is a constant no-op — no locks, no allocation beyond the call
@@ -41,7 +51,7 @@ class Span:
     """One timed interval. Use as a context manager or end() explicitly."""
 
     __slots__ = ("name", "span_id", "parent_id", "trace_id", "attrs",
-                 "t0", "t1", "tid", "_tracer", "_token")
+                 "t0", "t1", "tid", "_tracer", "_token", "_mirror")
 
     def __init__(self, name: str, tracer: "Tracer",
                  parent: Optional["Span"] = None,
@@ -55,11 +65,15 @@ class Span:
             self.parent_id = 0
             self.trace_id = self.span_id
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
-        self.t0 = time.perf_counter()
-        self.t1: Optional[float] = None
         self.tid = threading.get_ident()
         self._tracer = tracer
         self._token = None
+        self._mirror = None
+        if tracer._annotation is not None:
+            self._mirror = tracer._annotation(name)
+            self._mirror.__enter__()
+        self.t0 = time.perf_counter()
+        self.t1: Optional[float] = None
 
     # -- recording ---------------------------------------------------
     def set(self, **attrs: Any) -> "Span":
@@ -73,6 +87,8 @@ class Span:
         if attrs:
             self.attrs.update(attrs)
         self.t1 = time.perf_counter()
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
         self._tracer._finish(self)
 
     @property
@@ -134,6 +150,15 @@ class NullSpan:
 NULL_SPAN = NullSpan()
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax is missing."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:             # pragma: no cover - jax not installed
+        return None
+    return TraceAnnotation
+
+
 class Tracer:
     """Collects spans; bounded buffer of finished spans, JSONL export."""
 
@@ -142,6 +167,7 @@ class Tracer:
         self._open: Dict[int, Span] = {}
         self._lock = threading.Lock()
         self._epoch = time.perf_counter()
+        self._annotation = _profiler_annotation()
         self.enabled = True
 
     # -- span creation -----------------------------------------------

@@ -377,3 +377,167 @@ def test_jax_hooks_snapshot_shape():
     assert set(snap) >= {"compile_events", "compile_seconds",
                          "jit_cache_entries", "live_arrays"}
     assert isinstance(snap["jit_cache_entries"], dict)
+
+
+# --- host stages of the jax engine and their profiler mirror ---------------
+
+def _jax_plan():
+    """A small jax-engine request: two forecast members; a greedy, a
+    refined greedy (through its -LS variant) and the baseline."""
+    plat, inst, prof = _setup()
+    other = generate_profile("S1", prof.T, plat, J=16, seed=4)
+    req = PlanRequest(instances=inst, profiles=[prof, other],
+                      variants=("asap", "slack", "slackR-LS"))
+    return Planner(plat, engine="jax"), req
+
+
+def _profile_options():
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0        # TraceMe annotations only
+    return opts
+
+
+def _host_events(log_dir, names):
+    """``{name: [(line index, event), ...]}`` of the host plane's events
+    in the newest ``.xplane.pb`` under ``log_dir``."""
+    import jax
+
+    (path,) = log_dir.glob("**/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    out: dict = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for k, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name in names:
+                    out.setdefault(ev.name, []).append((k, ev))
+    return out
+
+
+def _inside(inner, outer) -> bool:
+    return outer.start_ns <= inner.start_ns and inner.end_ns <= outer.end_ns
+
+
+@pytest.mark.device
+def test_jax_plan_spans_every_host_stage(traced):
+    planner, req = _jax_plan()
+    res = planner.plan(req)
+    assert res.engine == "jax"
+    by: dict = {}
+    for sp in traced.finished():
+        by.setdefault(sp.name, []).append(sp)
+
+    def one(name, parent):
+        (sp,) = by[name]
+        assert sp.parent_id == parent.span_id, name
+        return sp
+
+    (plan,) = by["plan"]
+    overlays = one("overlays", plan)
+    launch = one("bucket_launch", plan)
+    rows = one("bucket_rows", launch)
+    climb = one("ls_climb", plan)
+    prep = one("ls_prep", climb)
+    device = one("ls_device_climb", climb)
+    polish = one("ls_polish", climb)
+    assemble = one("assemble", plan)
+    validates = by["validate"]
+    assert [v.parent_id for v in validates] == [assemble.span_id] * 2
+
+    assert overlays.attrs == {"rows": 2, "refined": (False, True)}
+    assert rows.attrs == {"rows": launch.attrs["rows"]} == {"rows": 4}
+    assert prep.attrs == {"padded": False, "rows": 2,
+                          "N": req.instances.num_tasks}
+    assert assemble.attrs == {"cells": 2}
+    assert [v.attrs for v in validates] == [{"variants": 3}] * 2
+    assert overlays.t1 <= launch.t0 <= rows.t0 <= rows.t1 <= launch.t1
+    assert prep.t1 <= device.t0 <= device.t1 <= polish.t0
+    assert launch.t1 <= climb.t0 and climb.t1 <= assemble.t0
+
+    # ScheduleResult.seconds still times the whole launch and the whole
+    # climb, the host stages inside them included, and nothing past plan
+    cell = res.results[0][0]
+    greedy_s = cell["slack"].seconds
+    ls_s = cell["slackR-LS"].seconds - greedy_s
+    assert launch.duration <= greedy_s * launch.attrs["rows"] + 1e-9
+    assert greedy_s * launch.attrs["rows"] <= plan.duration
+    assert climb.duration <= ls_s * climb.attrs["rows"] + 1e-9
+    assert ls_s * climb.attrs["rows"] <= plan.duration
+
+
+@pytest.mark.device
+def test_an_untraced_plan_creates_no_span(monkeypatch):
+    planner, req = _jax_plan()
+    made = []
+    init = obs.Span.__init__
+
+    def counting(self, name, *args, **kwargs):
+        made.append(name)
+        init(self, name, *args, **kwargs)
+
+    monkeypatch.setattr(obs.Span, "__init__", counting)
+    prev = obs.set_tracer(None)
+    try:
+        planner.plan(req)
+        assert made == []
+        obs.set_tracer(obs.Tracer())        # the count does see spans
+        planner.plan(req)
+        assert {"plan", "overlays", "bucket_rows", "ls_prep", "assemble",
+                "validate"} <= set(made)
+    finally:
+        obs.set_tracer(prev)
+
+
+@pytest.mark.device
+def test_spans_mirror_onto_the_profiler_host_plane(traced, tmp_path):
+    import jax
+
+    planner, req = _jax_plan()
+    planner.plan(req)                       # compile outside the profile
+    traced.clear()
+    with jax.profiler.trace(str(tmp_path),
+                            profiler_options=_profile_options()):
+        planner.plan(req)
+    spans = {sp.name: sp for sp in traced.finished()}
+    names = ("plan", "overlays", "bucket_launch", "bucket_rows", "ls_climb",
+             "assemble", "validate")
+    events = _host_events(tmp_path, names)
+    ev = {}
+    for name in names[:-1]:
+        ((_, ev[name]),) = events[name]
+        assert abs(ev[name].duration_ns * 1e-9 - spans[name].duration) \
+            < 1e-3, name
+    for name in names[1:-1]:
+        assert _inside(ev[name], ev["plan"]), name
+    assert _inside(ev["bucket_rows"], ev["bucket_launch"])
+    assert len(events["validate"]) == 2
+    assert all(_inside(v, ev["assemble"]) for _, v in events["validate"])
+
+
+def test_a_span_ended_on_another_thread_is_mirrored_where_it_ends(
+        traced, tmp_path):
+    import jax
+
+    with jax.profiler.trace(str(tmp_path),
+                            profiler_options=_profile_options()):
+        with traced.span("starter"):
+            handed = traced.start("handed_over")
+
+        def finish():
+            with traced.span("ender"):
+                handed.end()
+
+        t = threading.Thread(target=finish)
+        t.start()
+        t.join()
+    events = _host_events(tmp_path, {"starter", "handed_over", "ender"})
+    ((k_start, starter),) = events["starter"]
+    ((k_end, ender),) = events["ender"]
+    ((k, ev),) = events["handed_over"]
+    assert k == k_end != k_start            # on the ending thread's line
+    assert starter.start_ns <= ev.start_ns <= starter.end_ns
+    assert ender.start_ns <= ev.end_ns <= ender.end_ns
+    assert abs(ev.duration_ns * 1e-9 - handed.duration) < 1e-3
