@@ -133,11 +133,24 @@ def gather_windows(rem, start, dur, *, mu: int):
     each window is one whole-row gather: an element-wise gather of
     ``N * W`` scalars runs at about 43M elements/s on a TPU v5e, slow
     enough to dominate the climb.
+
+    The matrix is built by doubling its width, ``log2(W)`` lane-dense
+    concatenations of two operands each: after the step with offset k,
+    ``m[r, j] = rem_pad[r + j]`` for ``j < 2k``. Stacking W shifted
+    copies instead lowers on TPU to W single-lane copies, each padded out
+    to 128 lanes in the tiled layout: on a v5e that build took longer
+    than the gain kernel itself. Both builds move the same f32 values, so the
+    windows are bit-identical.
     """
     t_total = rem.shape[0]
     rem_pad = jnp.pad(rem, (W, W))
     top = t_total + W
-    rows = jnp.stack([rem_pad[j:j + top + 1] for j in range(W)], axis=1)
+    rows = rem_pad[:, None]
+    k = 1
+    while k < W:
+        n = rows.shape[0] - k
+        rows = jnp.concatenate([rows[:n], rows[k:k + n]], axis=1)
+        k *= 2
     s_i = start.astype(jnp.int32)
     e_i = (start + dur).astype(jnp.int32)
     win_s = rows[jnp.clip(s_i + W - mu, 0, top)]

@@ -68,25 +68,42 @@ def test_gain_scan_sweep(n, t, mu):
     np.testing.assert_allclose(got[legal], want[legal], atol=1e-3)
 
 
-@pytest.mark.parametrize("n,t,mu", [(5, 16, 1), (300, 512, 10),
-                                    (64, 777, 42)])
-def test_gather_windows_matches_elementwise_definition(n, t, mu):
+def _window_case(n, t, mu, rows=None):
+    return pytest.param(n, t, mu, rows, id=f"{n}-{t}-{mu}" + (
+        f"-vmap{rows}" if rows else ""))
+
+
+@pytest.mark.parametrize("n,t,mu,rows", [
+    _window_case(5, 16, 1), _window_case(300, 512, 10),
+    _window_case(64, 777, 42),
+    _window_case(1792, 512, 10),           # the 1000-task cell's buckets
+    _window_case(40, 100, 10),             # T shorter than W, not 2**k
+    _window_case(1792, 512, 10, rows=8),   # the climb's vmap over rows
+])
+def test_gather_windows_matches_elementwise_definition(n, t, mu, rows):
     from repro.kernels.gain_scan import W, gather_windows
 
     rng = np.random.default_rng(n + t + mu)
-    rem = rng.integers(-9, 9, t).astype(np.float32)
+    b = rows or 1
+    rem = rng.integers(-9, 9, (b, t)).astype(np.float32)
     dur = rng.integers(1, 9, n)
-    start = rng.integers(0, t - dur + 1)
-    start[:2], dur[:2] = (0, t - 3), (1, 3)    # both horizon edges
-    win_s, win_e = gather_windows(jnp.asarray(rem),
-                                  jnp.asarray(start, jnp.int32),
-                                  jnp.asarray(dur, jnp.int32), mu=mu)
-    rem_pad = np.pad(rem, (W, W))
+    start = rng.integers(0, t - dur + 1, (b, n))
+    start[:, :2], dur[:2] = (0, t - 3), (1, 3)    # both horizon edges
+    args = (jnp.asarray(rem), jnp.asarray(start, jnp.int32))
+    dur_j = jnp.asarray(dur, jnp.int32)
+    if rows:
+        win_s, win_e = jax.vmap(
+            lambda r, s: gather_windows(r, s, dur_j, mu=mu))(*args)
+    else:
+        win_s, win_e = gather_windows(args[0][0], args[1][0], dur_j, mu=mu)
+        win_s, win_e = win_s[None], win_e[None]
     j = np.arange(W)[None, :] - mu + W
-    np.testing.assert_array_equal(np.asarray(win_s),
-                                  rem_pad[start[:, None] + j])
-    np.testing.assert_array_equal(np.asarray(win_e),
-                                  rem_pad[(start + dur)[:, None] + j])
+    for r in range(b):
+        rem_pad = np.pad(rem[r], (W, W))
+        np.testing.assert_array_equal(np.asarray(win_s[r]),
+                                      rem_pad[start[r][:, None] + j])
+        np.testing.assert_array_equal(np.asarray(win_e[r]),
+                                      rem_pad[(start[r] + dur)[:, None] + j])
 
 
 def test_kernel_cost_matches_core_oracle():

@@ -12,6 +12,7 @@ The topology is described only inside the ``topo`` fixture: loading the
 TPU library while a module is imported would break multi-worker runs.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,4 +119,13 @@ def test_climb_compiles_with_gain_kernel(one_chip, monkeypatch, padded, n,
                           _spec(one_chip, (), i32),
                           _spec(one_chip, (n,), i32),
                           _spec(one_chip, (n,), i32), *adj)
-    assert "tpu_custom_call" in lowered.compile().as_text()
+    hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" in hlo
+    # the gain kernel is the climb's only Mosaic kernel: the benchmark
+    # times every custom call inside the climb as that kernel
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    # the sliding-window matrix is built lane-dense, not by a concatenate
+    # of W single-lane columns
+    widest = max((len(re.findall(r"%[\w.\-]+", args)) for args in
+                  re.findall(r" concatenate\(([^)]*)\)", hlo)), default=0)
+    assert widest <= 8, f"a concatenate of {widest} operands"
