@@ -461,17 +461,29 @@ def _blocked_fanout_padded(dur, work, blp: BlockedLP, budgets, masks,
     impl = _blocked_impl()["multi"]
     dur_j, work_j = jnp.asarray(dur), jnp.asarray(work)
     n_chunks = -(-Np // B)
+    N = blp.inst.num_tasks
+    swept = 0
     with obs.span("blocked_chunk_sweep", N=int(Np), chunk_width=int(B),
                   chunks=n_chunks, rows=int(P * V)):
         for c in range(0, Np, B):
             vs = orders[:, c:c + B]
-            rows, cols = blp.chunk_tensors(vs, Np)
+            tasks = len(np.unique(vs[vs < N]))
+            swept += tasks
+            # the host max-plus sweeps of this chunk's lp rows and columns;
+            # the rest of blocked_chunk_sweep is the chunk launches
+            with obs.span("blocked_lp_rows", chunk=c // B, tasks=tasks):
+                rows, cols = blp.chunk_tensors(vs, Np)
             state = impl(dur_j, work_j, *state, jnp.asarray(vs),
                          jnp.asarray(rows), jnp.asarray(cols))
-    obs.registry().counter(
+    reg = obs.registry()
+    reg.counter(
         "blocked_lp_chunks_total",
         "device chunk launches of the blocked longest-path sweep"
     ).inc(n_chunks)
+    reg.counter(
+        "blocked_lp_rows_total",
+        "unique tasks whose lp rows and columns were swept on the host"
+    ).inc(swept)
     return np.asarray(state[4])
 
 

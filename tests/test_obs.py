@@ -492,6 +492,51 @@ def test_an_untraced_plan_creates_no_span(monkeypatch):
 
 
 @pytest.mark.device
+@pytest.mark.parametrize("variants", [("slack",),
+                                      ("slack", "pressW", "slackWR")])
+def test_blocked_lp_rows_spans_each_chunk_sweep(traced, variants):
+    from repro.core.greedy_jax import lp_block_bytes, lp_matrix_bytes, \
+        pad_dims
+
+    plat, inst, prof = _setup()
+    N = inst.num_tasks
+    Np, _ = pad_dims(N, prof.T)
+    budget = lp_block_bytes(4, len(variants), Np)   # chunks of 4 steps
+    assert budget < lp_matrix_bytes(N)
+    req = PlanRequest(instances=inst, profiles=[prof], variants=variants)
+    swept = obs.registry().counter("blocked_lp_rows_total")
+    before = swept.value()
+    blocked = Planner(plat, engine="jax", lp_budget_bytes=budget).plan(req)
+
+    (sweep,) = [sp for sp in traced.finished()
+                if sp.name == "blocked_chunk_sweep"]
+    rows = [sp for sp in traced.finished() if sp.name == "blocked_lp_rows"]
+    assert sweep.attrs["chunks"] == Np // 4 == len(rows)
+    assert [sp.attrs["chunk"] for sp in rows] == list(range(len(rows)))
+    assert all(sp.parent_id == sweep.span_id for sp in rows)
+    assert all(sweep.t0 <= sp.t0 <= sp.t1 <= sweep.t1 for sp in rows)
+    # a chunk sweeps each of its real tasks once, whatever the orders
+    # share; with one order every task is swept exactly once
+    tasks = [sp.attrs["tasks"] for sp in rows]
+    assert all(t <= 4 * len(variants) for t in tasks)
+    assert swept.value() - before == sum(tasks)
+    if len(variants) == 1:
+        assert sum(tasks) == N
+
+    prev = obs.set_tracer(None)
+    try:
+        untraced = Planner(plat, engine="jax",
+                           lp_budget_bytes=budget).plan(req)
+    finally:
+        obs.set_tracer(prev)
+    dense = Planner(plat, engine="jax").plan(req)
+    for v in variants:
+        start = blocked.results[0][0][v].start
+        assert np.array_equal(start, untraced.results[0][0][v].start), v
+        assert np.array_equal(start, dense.results[0][0][v].start), v
+
+
+@pytest.mark.device
 def test_spans_mirror_onto_the_profiler_host_plane(traced, tmp_path):
     import jax
 
