@@ -42,11 +42,13 @@ envelope (default :data:`LP_MAX_BYTES`):
 * dense — the O(N^2) int32 matrix above, resident on device; the fast path
   for the replanning regime (N ~ 10^2-10^3);
 * blocked (:class:`BlockedLP`) — the big-instance path: the scan streams
-  the placement order in fixed-width chunks, and per chunk a host-side
-  block-wise max-plus sweep over the level-ordered adjacency produces just
-  that chunk's lp rows (descendant distances of the placed tasks) and
-  columns (ancestor distances), fed to the chunked scan as ``lax.scan``
-  inputs while the greedy state stays device-resident between chunk
+  the placement order in fixed-width chunks, and per chunk one device
+  program runs level-synchronous max-plus sweeps over the graph's level
+  tables (:attr:`BlockedLP.sweep_tables`, built once per graph) to produce
+  just that chunk's lp rows (descendant distances of the placed tasks) and
+  columns (ancestor distances), then feeds them to the chunked scan as
+  ``lax.scan`` inputs; the host only uploads the chunk's task ids and
+  dispatches, and the greedy state stays device-resident between chunk
   launches. Peak lp memory is O(N * B) for chunk width B
   (:meth:`BlockedLP.chunk_width` picks B from the budget), so instances far
   past the dense envelope schedule on ``engine="jax"`` — bit-identical to
@@ -134,11 +136,15 @@ def longest_path_matrix(inst: Instance,
 class BlockedLP:
     """Blocked longest-path relaxation: the O(N*B) streaming form.
 
-    Holds no matrix at all — :meth:`rows` and :meth:`cols` run the
-    forward/backward max-plus sweep over the topo-ordered adjacency for
-    just the requested tasks, and :meth:`chunk_tensors` assembles the
-    bucket-padded per-chunk scan inputs the blocked device scan consumes
-    (``repro.core.greedy_jax._blocked_impl``). Values are bit-identical
+    Holds no matrix at all. The served scan builds each chunk's lp rows
+    and columns on the device (``_blocked_impl``) by level-synchronous
+    max-plus sweeps over :attr:`sweep_tables`, the graph's edges grouped
+    by level, uploaded once. On the host, :meth:`rows` and :meth:`cols`
+    run the same forward/backward sweeps over the topo-ordered adjacency
+    for just the requested tasks: they build the dense matrix
+    (:func:`longest_path_matrix`), and :meth:`chunk_tensors` assembles
+    from them the bucket-padded per-chunk scan inputs the device build
+    must equal, bit for bit (the tests' oracle). Values are bit-identical
     to the canonical dense :func:`longest_path_matrix` entries
     (``materialize`` assembles the full matrix for differential tests).
 
@@ -188,6 +194,40 @@ class BlockedLP:
         d[np.arange(len(tasks)), tasks] = 0
         return d
 
+    @functools.cached_property
+    def sweep_tables(self):
+        """Level tables of the device sweeps, built once per graph:
+        ``(forward, backward)``, each a triple ``(dst, src, w)`` of int32
+        device arrays [L, E].
+
+        Row ``l`` holds the edges into the tasks of level ``l + 1``: for
+        the forward sweep (:meth:`rows`) the edges ``u -> v`` by ``v``'s
+        longest-path level from the sources, as ``(v, u, dur[u])``; for
+        the backward sweep (:meth:`cols`) by ``u``'s level from the
+        sinks, as ``(u, v, dur[u])``. No level holds both ends of an
+        edge, so relaxing a whole row at once reads only final values.
+        L and E are bucketed up (multiples of 8 and 64) so graphs of
+        similar shape share one compiled chunk program; the padding is
+        the self-loop ``(0, 0, 0)``, which relaxes nothing.
+        """
+        import jax.numpy as jnp
+
+        inst = self.inst
+        N = inst.num_tasks
+        dur = inst.dur.astype(np.int32)
+        # edges u -> v from the predecessor CSR
+        v = np.repeat(np.arange(N), np.diff(inst.pred_ptr))
+        u = np.asarray(inst.pred_idx, dtype=np.int64)
+        back = np.zeros(N, dtype=np.int64)
+        for t in inst.topo[::-1]:
+            ss = inst.succs(t)
+            if len(ss):
+                back[t] = back[ss].max() + 1
+        return tuple(
+            tuple(jnp.asarray(a) for a in _level_table(lev, dst, src, w))
+            for lev, dst, src, w in ((inst.level[v], v, u, dur[u]),
+                                     (back[u], u, v, dur[u])))
+
     def chunk_width(self, n_orders: int, padded_n: int) -> int:
         """Scan chunk width B for ``n_orders`` score orders at padded task
         count ``padded_n``: the largest width whose chunk buffers fit
@@ -210,10 +250,12 @@ class BlockedLP:
         return B
 
     def chunk_tensors(self, vs: np.ndarray, padded_n: int):
-        """Per-chunk scan inputs for order chunk ``vs`` [V, B]: int32
-        (rows, cols), each [V, B, padded_n]. Padded task ids (>= N) get
-        the padded identity row/column (``NEG_PATH`` off-diagonal, 0 on
-        it), exactly the dense padded matrix's entries."""
+        """Per-chunk scan inputs for order chunk ``vs`` [V, B], built on
+        the host: int32 (rows, cols), each [V, B, padded_n]. Padded task
+        ids (>= N) get the padded identity row/column (``NEG_PATH``
+        off-diagonal, 0 on it), exactly the dense padded matrix's
+        entries. The served path builds the same tensors on the device
+        (``_blocked_impl``); this is the oracle it is tested against."""
         V, B = vs.shape
         flat = np.asarray(vs, dtype=np.int64).ravel()
         N = self.inst.num_tasks
@@ -238,6 +280,24 @@ class BlockedLP:
             idx = np.arange(c, min(c + max(int(block), 1), N))
             out[idx] = self.rows(idx)
         return out
+
+
+def _level_table(level, dst, src, w) -> np.ndarray:
+    """Edges ``(dst, src, w)`` grouped by ``level`` (>= 1) into an int32
+    [3, L, E] table: row ``l`` holds level ``l + 1``'s edges sorted by
+    ``dst``, after the self-loop ``(0, 0, 0)`` padding (so each row's
+    ``dst`` stays sorted). L and E are bucketed up to 8 and 64."""
+    L = _bucket_up(int(level.max(initial=0)), 8)
+    row = level - 1
+    order = np.lexsort((dst, row))
+    row, dst, src, w = row[order], dst[order], src[order], w[order]
+    counts = np.bincount(row, minlength=L)
+    E = _bucket_up(int(counts.max(initial=0)), 64)
+    first = np.cumsum(counts) - counts
+    slot = np.arange(len(row)) - first[row] + (E - counts[row])
+    table = np.zeros((3, L, E), dtype=np.int32)
+    table[:, row, slot] = dst, src, w
+    return table
 
 
 def lp_for(inst: Instance, budget_bytes: int | None = None):
@@ -389,16 +449,49 @@ def _grid_launch(stacked, devices):
     return out[:n] if pad else out
 
 
+def _lp_sweep(jnp, lax, vs, table, padded_n):
+    """One chunk's lp rows (forward ``table``) or columns (backward
+    ``table``) on the device: int32 [V, B, padded_n] for the sources
+    ``vs`` [V, B], bit-identical to :meth:`BlockedLP.chunk_tensors`.
+
+    The state is task-major, ``d[t]`` = task ``t``'s distances from the
+    V*B sources, source ``vs[v, b]`` at lane ``b * V + v``: a level's
+    gather and scatter move whole rows, one (8, 128) tile each at 1024
+    sources. :func:`repro.kernels.maxplus_sweep.relax_levels` relaxes the
+    levels in order, each as ``d[dst] = max(d[dst], d[src] + w)``;
+    max-plus over int32 is exact and order-free, so this is the host
+    sweep's fixpoint. Then the host sweep's canonical form: negatives
+    become ``NEG_PATH`` and each source's own entry 0 (padded ids have no
+    edges: the identity row).
+    """
+    from repro.kernels.maxplus_sweep import relax_levels
+
+    V, B = vs.shape
+    C = V * B
+    lanes = 128 if C % 128 == 0 else C
+    shape = (padded_n, C // lanes, lanes)
+    # step-major lanes: the transpose back lands in the layout the
+    # vmapped scan reads its inputs in, with no second copy
+    own = lax.broadcasted_iota(jnp.int32, shape, 0) \
+        == vs.T.reshape(1, C // lanes, lanes)
+    neg = jnp.int32(NEG_PATH)
+    d = relax_levels(jnp.where(own, jnp.int32(0), neg), *table)
+    d = jnp.where(own, jnp.int32(0), jnp.where(d < 0, neg, d))
+    return d.reshape(padded_n, C).T.reshape(B, V, padded_n).swapaxes(0, 1)
+
+
 @functools.lru_cache(maxsize=1)
 def _blocked_impl():
-    """The chunked twin of :func:`_impl`: one ``lax.scan`` over a chunk of
-    the placement order, lp rows/cols arriving as scan inputs instead of a
-    device-resident matrix, full greedy state (rem, mask, est, lst, start)
-    returned so the host chunk loop keeps it device-resident between
-    launches. The step body IS :func:`_placement_step` — the same closure
-    the dense scan runs — with ``row``/``col`` arriving as scan inputs
-    instead of matrix lookups, so chunked results are bit-identical to
-    the dense scan's by construction."""
+    """The chunked twin of :func:`_impl`: one program per chunk of the
+    placement order that builds the chunk's lp rows and columns on the
+    device (:func:`_lp_sweep` over :attr:`BlockedLP.sweep_tables`), then
+    runs one ``lax.scan`` over the chunk with those rows/cols as scan
+    inputs instead of a device-resident matrix, and returns the full
+    greedy state (rem, mask, est, lst, start) so the host chunk loop
+    keeps it device-resident between launches. The step body IS
+    :func:`_placement_step` — the same closure the dense scan runs — so
+    chunked results are bit-identical to the dense scan's by
+    construction."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -418,16 +511,21 @@ def _blocked_impl():
     # diverge across variants and profiles between chunk launches)
     variant_axes = (None, None, 0, 0, 0, 0, 0, 0, 0, 0)
     profile_axes = (None, None, 0, 0, 0, 0, 0, None, None, None)
-    fanout = jax.vmap(chunk_scan, in_axes=variant_axes)
-    multi = jax.vmap(fanout, in_axes=profile_axes)
+    multi = jax.vmap(jax.vmap(chunk_scan, in_axes=variant_axes),
+                     in_axes=profile_axes)
+
+    def chunk(dur, work, rem, mask, est, lst, start, vs, forward,
+              backward):
+        Np = dur.shape[0]
+        rows = _lp_sweep(jnp, lax, vs, forward, Np)
+        cols = _lp_sweep(jnp, lax, vs, backward, Np)
+        return multi(dur, work, rem, mask, est, lst, start, vs, rows, cols)
+
     # donate the state buffers so chained chunk launches reuse device
     # memory (the state comes back with the same shapes; on CPU donation
     # is a no-op that only warns, so it is enabled off-CPU only)
     don = tuple(range(2, 7)) if jax.default_backend() != "cpu" else ()
-    return {
-        "fanout": jax.jit(fanout, donate_argnums=don),
-        "multi": jax.jit(multi, donate_argnums=don),
-    }
+    return {"multi": jax.jit(chunk, donate_argnums=don)}
 
 
 def _blocked_fanout_padded(dur, work, blp: BlockedLP, budgets, masks,
@@ -459,6 +557,7 @@ def _blocked_fanout_padded(dur, work, blp: BlockedLP, budgets, masks,
         jnp.asarray(np.zeros((P, V, Np), dtype=np.int32)),
     )
     impl = _blocked_impl()["multi"]
+    tables = blp.sweep_tables
     dur_j, work_j = jnp.asarray(dur), jnp.asarray(work)
     n_chunks = -(-Np // B)
     N = blp.inst.num_tasks
@@ -469,12 +568,11 @@ def _blocked_fanout_padded(dur, work, blp: BlockedLP, budgets, masks,
             vs = orders[:, c:c + B]
             tasks = len(np.unique(vs[vs < N]))
             swept += tasks
-            # the host max-plus sweeps of this chunk's lp rows and columns;
-            # the rest of blocked_chunk_sweep is the chunk launches
+            # the dispatch of this chunk's program: the device sweeps of
+            # its lp rows and columns, then its scan
             with obs.span("blocked_lp_rows", chunk=c // B, tasks=tasks):
-                rows, cols = blp.chunk_tensors(vs, Np)
-            state = impl(dur_j, work_j, *state, jnp.asarray(vs),
-                         jnp.asarray(rows), jnp.asarray(cols))
+                state = impl(dur_j, work_j, *state, jnp.asarray(vs),
+                             *tables)
     reg = obs.registry()
     reg.counter(
         "blocked_lp_chunks_total",
@@ -482,8 +580,12 @@ def _blocked_fanout_padded(dur, work, blp: BlockedLP, budgets, masks,
     ).inc(n_chunks)
     reg.counter(
         "blocked_lp_rows_total",
-        "unique tasks whose lp rows and columns were swept on the host"
+        "unique tasks whose lp rows and columns were swept"
     ).inc(swept)
+    reg.counter(
+        "blocked_lp_device_sweeps_total",
+        "level-synchronous max-plus sweeps run on the device, two a chunk"
+    ).inc(2 * n_chunks)
     return np.asarray(state[4])
 
 

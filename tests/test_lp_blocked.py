@@ -97,6 +97,156 @@ def test_blocked_property_random_dags():
     prop()
 
 
+# --- the device build of a chunk's rows and columns -------------------------
+
+def _device_chunk(blp, vs, padded_n):
+    """The served chunk program's lp rows and columns for sources ``vs``,
+    built on the device from the graph's level tables."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from repro.core.greedy_jax import _lp_sweep
+
+    forward, backward = blp.sweep_tables
+    sweep = jax.jit(lambda v, t: _lp_sweep(jnp, lax, v, t, padded_n))
+    vs = jnp.asarray(vs, dtype=jnp.int32)
+    return np.asarray(sweep(vs, forward)), np.asarray(sweep(vs, backward))
+
+
+def _chunk_shapes(N, Np, rng):
+    """Source chunks [V, B]: B = 1, B = Np (padded ids included), and a
+    chunk in which one task appears in several orders and twice in one."""
+    many = rng.integers(0, N, size=(3, 8)).astype(np.int32)
+    many[:, 0] = many[0, 0]                  # one task in every order
+    many[1, 5] = many[0, 0]                  # and twice in one order
+    return [rng.integers(0, N, size=(4, 1)),
+            np.stack([rng.permutation(Np) for _ in range(2)]),
+            np.full((2, 4), Np - 1),         # a padded id, or the last task
+            many]
+
+
+@pytest.mark.device
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_device_chunk_rows_and_cols_equal_the_host_sweep(family):
+    inst, _ = _instance(family, 5)
+    N = inst.num_tasks
+    Np, _ = pad_dims(N, 1)
+    blp = BlockedLP(inst)
+    for vs in _chunk_shapes(N, Np, np.random.default_rng(5)):
+        want = blp.chunk_tensors(vs, Np)
+        got = _device_chunk(blp, vs, Np)
+        for g, w, what in zip(got, want, ("rows", "cols")):
+            assert g.dtype == w.dtype and g.shape == w.shape, what
+            assert np.array_equal(g, w), (family, vs.shape, what)
+
+
+@pytest.mark.device
+@pytest.mark.parametrize("family", ["atacseq", "eager", "layered_random"])
+def test_maxplus_kernel_equals_its_jnp_twin(family):
+    """The Pallas sweep (under the interpreter) and the jnp twin that
+    serves CPU relax a chunk's state to the same int32 values, in both
+    directions and in both row layouts the chunk program uses."""
+    import jax.numpy as jnp
+
+    from repro.kernels.maxplus_sweep import relax_levels
+
+    inst, _ = _instance(family, 1)
+    Np, _ = pad_dims(inst.num_tasks, 1)
+    rng = np.random.default_rng(1)
+    for table in BlockedLP(inst).sweep_tables:
+        for shape in ((Np, 1, 12), (Np, 2, 128)):
+            d0 = jnp.asarray(np.where(rng.random(shape) < 0.05, 0,
+                                      NEG_PATH).astype(np.int32))
+            twin = np.asarray(relax_levels(d0, *table))
+            kernel = np.asarray(relax_levels(d0, *table, interpret=True))
+            assert np.array_equal(kernel, twin), (family, shape)
+            assert (twin >= np.asarray(d0)).all()
+
+
+@pytest.mark.device
+def test_device_chunk_property_random_dags():
+    pytest.importorskip("hypothesis")
+    from hypothesis import given, settings, strategies as st
+
+    @settings(max_examples=15, deadline=None)
+    @given(n=st.integers(6, 40), layers=st.integers(2, 6),
+           p=st.floats(0.05, 0.5), seed=st.integers(0, 10_000),
+           V=st.integers(1, 4), B=st.integers(1, 16))
+    def prop(n, layers, p, seed, V, B):
+        wf = layered_random(n, layers, p_edge=p, seed=seed)
+        plat = make_cluster(1, seed=seed % 5)
+        inst = build_instance(wf, trivial_mapping(wf, plat), plat)
+        Np, _ = pad_dims(inst.num_tasks, 1)
+        vs = np.random.default_rng(seed).integers(0, Np, size=(V, B))
+        blp = BlockedLP(inst)
+        want = blp.chunk_tensors(vs, Np)
+        got = _device_chunk(blp, vs, Np)
+        assert np.array_equal(got[0], want[0])
+        assert np.array_equal(got[1], want[1])
+
+    prop()
+
+
+@pytest.mark.parametrize("family", ["atacseq", "independent_tasks",
+                                    "layered_random"])
+def test_sweep_tables_hold_each_edge_once_by_level(family):
+    """Each edge sits in exactly one row of each table, in the row of its
+    head's level, with a tail of a lower level; the rest of a row is the
+    no-op self-loop (0, 0, 0) ahead of the row's sorted heads."""
+    inst, _ = _instance(family, 2)
+    N = inst.num_tasks
+    v = np.repeat(np.arange(N), np.diff(inst.pred_ptr))
+    u = inst.pred_idx
+    back = np.zeros(N, dtype=np.int64)
+    for t in inst.topo[::-1]:
+        if len(inst.succs(t)):
+            back[t] = back[inst.succs(t)].max() + 1
+    want = {"forward": (inst.level, v, u), "backward": (back, u, v)}
+    for name, table in zip(want, BlockedLP(inst).sweep_tables):
+        dst, src, w = (np.asarray(a) for a in table)
+        level, heads, tails = want[name]
+        L, E = dst.shape
+        assert L % 8 == 0 and E % 64 == 0 and L >= level.max()
+        real = ~((dst == 0) & (src == 0) & (w == 0))
+        rows = np.nonzero(real)[0]
+        got = sorted(zip(dst[real], src[real], w[real]))
+        assert got == sorted(zip(heads, tails, inst.dur[u])), name
+        assert (level[dst[real]] == rows + 1).all(), name
+        assert (level[src[real]] < level[dst[real]]).all(), name
+        for row in range(L):
+            assert (np.diff(dst[row]) >= 0).all(), (name, row)
+            k = int((~real[row]).sum())
+            assert not real[row, :k].any(), (name, row)
+
+
+@pytest.mark.device
+def test_served_blocked_plan_runs_no_host_sweep(monkeypatch):
+    """The served blocked path builds its lp rows and columns on the
+    device: with the host sweeps tripwired, the blocked grid still runs
+    and equals the dense one, start for start."""
+    inst, plat = _instance("methylseq", 4)
+    T = deadline_from_asap(inst, 1.5)
+    prof = generate_profile("S2", T, plat, J=16, seed=4)
+    dense = schedule_portfolio_grid([inst], [[prof]], plat, engine="jax",
+                                    variants=("press", "slackWR"))
+    graph = prepare_graph(inst, plat, T,
+                          lp_budget_bytes=_force_blocked_budget(inst, T))
+    assert graph.lp_is_blocked
+
+    def _no_host_sweep(*a, **k):
+        raise AssertionError("host longest-path sweep on the served path")
+
+    for name in ("rows", "cols", "chunk_tensors"):
+        monkeypatch.setattr(BlockedLP, name, _no_host_sweep)
+    blocked = schedule_portfolio_grid([inst], [[prof]], plat, engine="jax",
+                                      variants=("press", "slackWR"),
+                                      graphs=[graph])
+    for name, ref in dense[0][0].items():
+        assert np.array_equal(blocked[0][0][name].start, ref.start), name
+        assert blocked[0][0][name].cost == ref.cost, name
+
+
 # --- downstream schedules (greedy fan-out + device local search) ------------
 
 def _n_orders():

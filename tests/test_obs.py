@@ -505,7 +505,8 @@ def test_blocked_lp_rows_spans_each_chunk_sweep(traced, variants):
     assert budget < lp_matrix_bytes(N)
     req = PlanRequest(instances=inst, profiles=[prof], variants=variants)
     swept = obs.registry().counter("blocked_lp_rows_total")
-    before = swept.value()
+    sweeps = obs.registry().counter("blocked_lp_device_sweeps_total")
+    before, sweeps_before = swept.value(), sweeps.value()
     blocked = Planner(plat, engine="jax", lp_budget_bytes=budget).plan(req)
 
     (sweep,) = [sp for sp in traced.finished()
@@ -522,6 +523,8 @@ def test_blocked_lp_rows_spans_each_chunk_sweep(traced, variants):
     assert swept.value() - before == sum(tasks)
     if len(variants) == 1:
         assert sum(tasks) == N
+    # each chunk's rows and columns: two device sweeps a chunk
+    assert sweeps.value() - sweeps_before == 2 * len(rows)
 
     prev = obs.set_tracer(None)
     try:

@@ -98,6 +98,34 @@ def test_greedy_grid_compiles(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+def test_blocked_chunk_program_compiles(one_chip, monkeypatch):
+    """The blocked form's chunk program, the device sweeps of a chunk's lp
+    rows and columns and then its scan, at a small bucket: 1024 tasks, 32
+    levels of up to 128 edges, chunks of 64 steps x 8 orders."""
+    from repro.core.greedy_jax import _blocked_impl
+    from repro.kernels import maxplus_sweep
+
+    # the sweep picks its executor by the backend it sees, which is the
+    # CPU here: steer it to the compiled kernel the chip would take
+    monkeypatch.setattr(maxplus_sweep, "resolve_mode", lambda _: "pallas")
+
+    n, t, steps, levels, edges, i32 = 1024, 256, 64, 32, 128, jnp.int32
+    state = (_spec(one_chip, (PROFILES, COMBOS, t), i32),            # rem
+             _spec(one_chip, (PROFILES, COMBOS, t + 1), jnp.bool_),  # mask
+             *(_spec(one_chip, (PROFILES, COMBOS, n), i32),) * 3)
+    table = (_spec(one_chip, (levels, edges), i32),) * 3
+    compiled = _blocked_impl()["multi"].lower(
+        _spec(one_chip, (n,), i32), _spec(one_chip, (n,), i32), *state,
+        _spec(one_chip, (COMBOS, steps), i32), table, table).compile()
+    hlo = compiled.as_text()
+    # one Mosaic kernel a sweep: the rows', then the columns'
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    # the working set is the chunk's rows and columns, not more: 2 x 8
+    # orders x 64 steps x 1024 tasks x 4 bytes, plus the sweep state
+    lp = 2 * COMBOS * steps * n * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * lp
+
+
 @pytest.mark.parametrize("padded,n,t", [(False, N_BUCKET_1K, T_BUCKET_1K),
                                         (True, N_BUCKET_4K, T_BUCKET_4K)])
 def test_climb_compiles_with_gain_kernel(one_chip, monkeypatch, padded, n,
