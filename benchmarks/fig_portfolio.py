@@ -210,7 +210,7 @@ def _lp_blocked_section(cases) -> dict:
 
     # steady state: re-running the blocked path must add zero jit cache
     # misses — neither to its own chunked executable nor to the dense grid
-    grid_fn, blk_fn = _impl()["grid"], _blocked_impl()["multi"]
+    grid_fn, blk_fn = _impl(), _blocked_impl()
     before = grid_fn._cache_size() + blk_fn._cache_size()
     schedule_portfolio_grid([inst], [[prof]], plat, engine="jax",
                             graphs=[g_blk])
@@ -622,7 +622,7 @@ def run(sizes=(200,), clusters=("small",), n_cases: int = 6,
         grid_req = PlanRequest(instances=insts,
                                profiles=[profs, profs_b])
         buckets = {pad_dims(i.num_tasks, profs[0].T) for i in insts}
-        grid_fn = _impl()["grid"]
+        grid_fn = _impl()
         before = grid_fn._cache_size()
         planner.plan(grid_req)                  # cold: compiles per bucket
         misses_cold = grid_fn._cache_size() - before

@@ -17,19 +17,17 @@ by the direct matrix entry). The scan step is O(N + T) with no nested
 scans, so the program compiles in a fraction of the old level-relax
 formulation's time and executes orders of magnitude faster on CPU.
 
-Three vmap levels over the same scan core, all served by one jit cache:
-
-* variants — score orders and candidate masks batched (``greedy_fanout_jax``);
-* profiles — budget timelines and masks batched on an outer axis
-  (``greedy_fanout_multi_jax``; same shapes by construction, the
-  multi-profile replanning fan-out);
-* instances — shape-bucketed batches
-  (``repro.core.portfolio.portfolio_starts_batch``).
+One launch serves every request shape: :func:`greedy_fanout_grid_jax`
+runs the scan vmapped over variants (score orders and candidate masks) x
+profiles (an ensemble's budget timelines and masks) x instances (the
+stacked rows of one shape bucket) as ONE jitted program (:func:`_impl`),
+or ``shard_map``-ed over the instance axis across devices
+(:func:`_grid_sharded_impl`); both wrap the same traced function.
 
 Retracing discipline: all inputs are padded to shape buckets
 (:func:`pad_dims` — N to multiples of 128, T to multiples of 256) before
-they reach the jitted entry points, so instances whose real shapes differ
-hit the same compiled executable; the jit cache is effectively keyed on the
+they reach the launch, so instances whose real shapes differ hit the same
+compiled executable; the jit cache is effectively keyed on the
 bucket tuple. Padding is output-invariant: padded tasks have zero
 duration/work and place at t=0 (a candidate point on every profile), padded
 time units are never feasible starts (mask False, and every real LST is
@@ -66,12 +64,7 @@ import functools
 import numpy as np
 
 from repro import obs
-from repro.cluster import Platform
-from repro.core.carbon import PowerProfile
 from repro.core.dag import Instance
-from repro.core.estlst import compute_est, compute_lst
-from repro.core.scores import task_order
-from repro.core.subdivide import candidate_mask
 
 NEG_PATH = -(1 << 30)                  # "no path" marker in lp (int32-safe)
 
@@ -354,13 +347,13 @@ def _placement_step(jnp, dur, work):
 
 
 @functools.lru_cache(maxsize=1)
-def _build_fns():
-    """Unjitted greedy launchers (scan / variant-vmap / profile-vmap).
+def _grid_fn():
+    """The unjitted grid launcher: the §5.2 scan vmapped over variants,
+    then profiles, then instances.
 
-    Shared by :func:`_impl` (which jits them) and
-    :func:`_grid_sharded_impl` (which wraps the instance-level vmap in a
-    ``shard_map`` before jitting), so both launch paths trace the SAME
-    closures and stay bit-identical by construction.
+    Shared by :func:`_impl` (which jits it) and :func:`_grid_sharded_impl`
+    (which wraps it in a ``shard_map`` before jitting), so both launch
+    paths trace the SAME closure and stay bit-identical by construction.
     """
     import jax
     import jax.numpy as jnp
@@ -383,32 +376,29 @@ def _build_fns():
     profile_axes = (None, None, None, 0, 0, None, None, None)
     fanout = jax.vmap(greedy_scan, in_axes=variant_axes)
     multi = jax.vmap(fanout, in_axes=profile_axes)
-    return greedy_scan, fanout, multi
+    return jax.vmap(multi, in_axes=(0,) * 8)
 
 
 @functools.lru_cache(maxsize=1)
 def _impl():
+    """The jitted single-device grid launch."""
     # no buffer donation: the only output (start times) matches no input's
     # shape, so nothing could alias a donated budget or mask buffer
     import jax
 
-    greedy_scan, fanout, multi = _build_fns()
-    return {
-        "single": jax.jit(greedy_scan),
-        "fanout": jax.jit(fanout),
-        "multi": jax.jit(multi),
-        "batch": jax.jit(jax.vmap(fanout, in_axes=(0,) * 8)),
-        "grid": jax.jit(jax.vmap(multi, in_axes=(0,) * 8)),
-    }
+    return jax.jit(_grid_fn())
 
 
-@functools.lru_cache(maxsize=8)
+_sharded_grids: dict = {}      # ndev -> jitted sharded grid, built on use
+
+
 def _grid_sharded_impl(ndev: int):
-    """The grid launcher sharded over ``ndev`` devices.
+    """The grid launcher sharded over ``ndev`` devices, built once per
+    device count (kept in ``_sharded_grids`` for the jit-cache probe).
 
     The instance-row axis of the combined (instances x profiles x
     variants) launch is embarrassingly parallel, so the sharded form is a
-    ``shard_map`` of the same instance-level vmap over a 1-D "data" mesh
+    ``shard_map`` of the same grid function over a 1-D "data" mesh
     (``sharding.ctx.grid_mesh``): every device runs ``rows/ndev`` full
     greedy scans with zero cross-device communication, and the result is
     bitwise-identical to the single-device grid (rows are independent and
@@ -416,18 +406,18 @@ def _grid_sharded_impl(ndev: int):
     ``check_vma=False``: no replicated outputs to verify, and the scan
     body trips the conservative varying-axes checker.
     """
+    if ndev in _sharded_grids:
+        return _sharded_grids[ndev]
     import jax
 
     from repro.sharding.ctx import grid_mesh
     from repro.sharding.specs import grid_batch_spec
 
-    _, _, multi = _build_fns()
-    grid = jax.vmap(multi, in_axes=(0,) * 8)
     spec = grid_batch_spec()
-    sharded = jax.shard_map(grid, mesh=grid_mesh(ndev),
+    sharded = jax.shard_map(_grid_fn(), mesh=grid_mesh(ndev),
                             in_specs=(spec,) * 8, out_specs=spec,
                             check_vma=False)
-    return jax.jit(sharded)
+    return _sharded_grids.setdefault(ndev, jax.jit(sharded))
 
 
 def _grid_launch(stacked, devices):
@@ -436,7 +426,7 @@ def _grid_launch(stacked, devices):
     to a multiple of the device count by repeating the last row, sliced
     off after — shard_map needs equal per-device block sizes)."""
     if devices is None or devices <= 1:
-        return _impl()["grid"](*stacked)
+        return _impl()(*stacked)
     import jax.numpy as jnp
 
     n = stacked[0].shape[0]
@@ -482,8 +472,8 @@ def _lp_sweep(jnp, lax, vs, table, padded_n):
 
 @functools.lru_cache(maxsize=1)
 def _blocked_impl():
-    """The chunked twin of :func:`_impl`: one program per chunk of the
-    placement order that builds the chunk's lp rows and columns on the
+    """The jitted chunked twin of :func:`_impl`: one program per chunk of
+    the placement order that builds the chunk's lp rows and columns on the
     device (:func:`_lp_sweep` over :attr:`BlockedLP.sweep_tables`), then
     runs one ``lax.scan`` over the chunk with those rows/cols as scan
     inputs instead of a device-resident matrix, and returns the full
@@ -525,7 +515,7 @@ def _blocked_impl():
     # memory (the state comes back with the same shapes; on CPU donation
     # is a no-op that only warns, so it is enabled off-CPU only)
     don = tuple(range(2, 7)) if jax.default_backend() != "cpu" else ()
-    return {"multi": jax.jit(chunk, donate_argnums=don)}
+    return jax.jit(chunk, donate_argnums=don)
 
 
 def _blocked_fanout_padded(dur, work, blp: BlockedLP, budgets, masks,
@@ -556,7 +546,7 @@ def _blocked_fanout_padded(dur, work, blp: BlockedLP, budgets, masks,
         jnp.asarray(np.broadcast_to(lst, (P, V, Np)).copy()),
         jnp.asarray(np.zeros((P, V, Np), dtype=np.int32)),
     )
-    impl = _blocked_impl()["multi"]
+    impl = _blocked_impl()
     tables = blp.sweep_tables
     dur_j, work_j = jnp.asarray(dur), jnp.asarray(work)
     n_chunks = -(-Np // B)
@@ -597,7 +587,7 @@ def padded_shared(inst: Instance, est0, lst0, lp=None):
     padded task ids every padded score order must end with. ``lp`` may be
     a precomputed dense matrix OR a :class:`BlockedLP` — the blocked
     handle passes through in the lp slot (no device matrix exists) and
-    the fan-outs route accordingly.
+    :func:`greedy_fanout_grid_jax` routes it to the chunked scan.
     """
     import jax.numpy as jnp
 
@@ -647,72 +637,9 @@ def pad_budget(unit_budget: np.ndarray, Tp: int) -> np.ndarray:
     return np.pad(np.asarray(unit_budget, np.int32), pad)
 
 
-def greedy_schedule_jax(inst: Instance, profile: PowerProfile,
-                        platform: Platform, score: str = "press",
-                        weighted: bool = False, refined: bool = False,
-                        k: int = 3, lp_budget_bytes: int | None = None):
-    """Jittable greedy; returns start times (int32 [N]). Instances past
-    the ``lp_budget_bytes`` dense envelope stream through the blocked
-    form (:class:`BlockedLP`), bit-identically."""
-    import jax.numpy as jnp
-
-    T = profile.T
-    est0 = compute_est(inst)
-    lst0 = compute_lst(inst, T)
-    if (est0 > lst0).any():
-        raise ValueError("infeasible: deadline below ASAP makespan")
-    order = task_order(inst, est0, lst0, score, weighted, platform)
-    mask0 = candidate_mask(inst, profile, refined=refined, k=k)
-    _, Tp = pad_dims(inst.num_tasks, T)
-    dur, work, lp, est_j, lst_j, tail = padded_shared(
-        inst, est0, lst0, lp_for(inst, lp_budget_bytes))
-    rem0 = pad_budget(profile.unit_budget(inst.idle_total), Tp)
-    order_p = pad_orders(np.asarray(order, np.int32)[None], tail)
-    if isinstance(lp, BlockedLP):
-        starts = _blocked_fanout_padded(
-            dur, work, lp, rem0[None], pad_masks(mask0, Tp)[None, None],
-            est_j, lst_j, order_p)
-        return starts[0, 0, :inst.num_tasks]
-    start = _impl()["single"](dur, work, lp, jnp.asarray(rem0),
-                              jnp.asarray(pad_masks(mask0, Tp)),
-                              est_j, lst_j, jnp.asarray(order_p[0]))
-    return start[:inst.num_tasks]
-
-
-def greedy_fanout_jax(inst: Instance, profile: PowerProfile, est0, lst0,
-                      masks: np.ndarray, orders: np.ndarray, lp=None,
-                      shared=None):
-    """All variants of one instance in one jitted vmapped scan.
-
-    Args:
-      masks:  bool [V, T+1] per-variant candidate masks.
-      orders: int  [V, N] per-variant score orders.
-      lp:     optional precomputed :func:`longest_path_matrix`.
-      shared: optional :func:`padded_shared` output (device-resident reuse).
-    Returns:
-      int32 [V, N] start times.
-    """
-    import jax.numpy as jnp
-
-    _, Tp = pad_dims(inst.num_tasks, profile.T)
-    dur, work, lp_j, est_j, lst_j, tail = \
-        shared if shared is not None else padded_shared(inst, est0, lst0, lp)
-    rem0 = pad_budget(profile.unit_budget(inst.idle_total), Tp)
-    if isinstance(lp_j, BlockedLP):
-        starts = _blocked_fanout_padded(
-            dur, work, lp_j, rem0[None], pad_masks(masks, Tp)[None],
-            est_j, lst_j, pad_orders(orders, tail))
-        return starts[0, :, :inst.num_tasks]
-    starts = _impl()["fanout"](
-        dur, work, lp_j, jnp.asarray(rem0),
-        jnp.asarray(pad_masks(masks, Tp)), est_j, lst_j,
-        jnp.asarray(pad_orders(orders, tail)))
-    return starts[:, :inst.num_tasks]
-
-
 def greedy_fanout_grid_jax(bucket_rows, devices: int | None = None):
     """All (instance, profile, variant) greedy schedules of one shape bucket
-    in ONE launch — the third vmap level (instances) over ``multi``.
+    in ONE launch: the only route to the greedy fan-out.
 
     Args:
       bucket_rows: per-instance tuples of bucket-padded device inputs in
@@ -755,33 +682,3 @@ def greedy_fanout_grid_jax(bucket_rows, devices: int | None = None):
             out[i] = _blocked_fanout_padded(dur, work, blp, budgets,
                                             masks, est_j, lst_j, orders)
     return np.stack([np.asarray(o) for o in out])
-
-
-def greedy_fanout_multi_jax(inst: Instance, T: int, unit_budgets: np.ndarray,
-                            masks: np.ndarray, orders: np.ndarray,
-                            est0=None, lst0=None, lp=None, shared=None):
-    """All (profile, variant) greedy schedules of one instance in ONE launch.
-
-    Args:
-      unit_budgets: int [P, T] per-profile effective budget timelines.
-      masks:        bool [P, V, T+1] per-(profile, variant) candidate masks.
-      orders:       int [V, N] score orders (profile-independent given T).
-    Returns:
-      int32 [P, V, N] start times.
-    """
-    import jax.numpy as jnp
-
-    _, Tp = pad_dims(inst.num_tasks, T)
-    if shared is None:
-        shared = padded_shared(inst, est0, lst0, lp)
-    dur, work, lp_j, est_j, lst_j, tail = shared
-    if isinstance(lp_j, BlockedLP):
-        starts = _blocked_fanout_padded(
-            dur, work, lp_j, pad_budget(unit_budgets, Tp),
-            pad_masks(masks, Tp), est_j, lst_j, pad_orders(orders, tail))
-        return starts[:, :, :inst.num_tasks]
-    starts = _impl()["multi"](
-        dur, work, lp_j, jnp.asarray(pad_budget(unit_budgets, Tp)),
-        jnp.asarray(pad_masks(masks, Tp)), est_j, lst_j,
-        jnp.asarray(pad_orders(orders, tail)))
-    return starts[:, :, :inst.num_tasks]

@@ -1,20 +1,15 @@
-"""Batched local search: device-resident gain/commit rounds + exact polish.
+"""The device hill climb: device-resident gain/commit rounds + exact polish.
 
 The paper's local search walks tasks sequentially and applies the first
-improving +-mu shift. The device climbers instead evaluate *all*
-(task, shift) gains at once (``kernels.gain_scan``) and commit proposals in
-gain order with exact integer re-evaluation. Two generations live here:
-
-* :func:`local_search_batched` — the host-loop version: one gain launch
-  per round, commits on host (``_commit_round``). One schedule at a time.
-* :func:`local_search_portfolio` / :func:`local_search_portfolio_multi` —
-  the portfolio engine's climber: ALL rows (``-LS`` variants x ensemble
-  profiles) advance together, and the whole gain/commit round loop runs
-  device-resident as ONE jitted ``lax.while_loop`` (gains via the
-  compiled Pallas kernel on TPU, its jnp prefix-sum twin on CPU; commits
-  as an in-loop top-K scan with exact integer re-evaluation) — one host
-  sync per hill climb, not one per round. Rows carry per-variant round
-  budgets and deactivate individually when a round commits nothing.
+improving +-mu shift. :func:`local_search_portfolio_multi`, the portfolio
+engine's one climber, instead evaluates *all* (task, shift) gains at once
+(``kernels.gain_scan``) and commits proposals in gain order with exact
+integer re-evaluation. ALL rows (``-LS`` variants x ensemble profiles) of
+an instance advance together, and the whole gain/commit round loop runs
+device-resident as ONE jitted ``lax.while_loop`` (gains via the compiled
+Pallas kernel on TPU, its jnp prefix-sum twin on CPU; commits as an
+in-loop top-K scan) — one host sync per hill climb, not one per round.
+Rows deactivate individually when a round commits nothing.
 
 After the device climb converges, every row is *polished* with the exact
 sequential reference (:func:`repro.core.local_search.reference_round`)
@@ -31,12 +26,9 @@ import numpy as np
 
 from repro import obs
 from repro.core.cancel import checkpoint
-from repro.core.carbon import PowerProfile, work_timeline
+from repro.core.carbon import work_timeline
 from repro.core.dag import Instance
-from repro.core.local_search import apply_move, dyn_bounds, \
-    ls_graph_context, move_gain, reference_round
-from repro.core.local_search import dyn_bounds_all as _dyn_windows
-from repro.kernels.ops import ls_gains
+from repro.core.local_search import ls_graph_context, reference_round
 
 _COMMIT_K = 32       # default device commits per row per round
 # (the rest wait a round; expose per call as LocalSearchConfig.commit_k)
@@ -57,62 +49,6 @@ def auto_commit_k(n_candidates: int,
     sequential-reference polish runs regardless.
     """
     return int(np.clip(int(n_candidates) // 4, lo, hi))
-
-
-def _commit_round(inst, T, rem, start, gains, mu) -> bool:
-    """Commit this round's kernel proposals in gain order, exactly."""
-    dur = inst.dur
-    work = inst.task_work
-    best_delta = np.argmax(gains, axis=1) - mu
-    best_gain = gains.max(axis=1)
-    cand = np.flatnonzero(best_gain > 0)
-    committed = False
-    for v in cand[np.argsort(-best_gain[cand], kind="stable")]:
-        v = int(v)
-        s = int(start[v])
-        e = s + int(dur[v])
-        new_s = s + int(best_delta[v])
-        dlo, dhi = dyn_bounds(inst, start, v, T)
-        new_s = min(max(new_s, dlo), dhi)
-        if new_s == s or dlo > dhi:
-            continue
-        g = move_gain(rem, s, e, new_s, int(work[v]))
-        if g <= 0:
-            continue
-        apply_move(rem, s, e, new_s, int(work[v]))
-        start[v] = new_s
-        committed = True
-    return committed
-
-
-def local_search_batched(inst: Instance, profile: PowerProfile,
-                         start: np.ndarray, mu: int = 10,
-                         max_rounds: int = 200,
-                         interpret: bool | None = None,
-                         cancel=None) -> np.ndarray:
-    T = profile.T
-    start = np.asarray(start, dtype=np.int64).copy()
-    rem = (profile.unit_budget(inst.idle_total)
-           - work_timeline(inst, T, start)).astype(np.int64)
-    dur = inst.dur
-    work = inst.task_work
-    N = inst.num_tasks
-
-    # edge arrays for vectorized dynamic bounds
-    edges = (np.repeat(np.arange(N), np.diff(inst.pred_ptr)), inst.pred_idx,
-             np.repeat(np.arange(N), np.diff(inst.succ_ptr)), inst.succ_idx)
-
-    for _ in range(max_rounds):
-        checkpoint(cancel)               # per-round cancellation rung
-        lo, hi = _dyn_windows(start, dur, T, edges)
-        gains = np.asarray(ls_gains(
-            rem.astype(np.float32), start.astype(np.float32),
-            dur.astype(np.float32), work.astype(np.float32),
-            lo.astype(np.float32), hi.astype(np.float32),
-            mu=mu, interpret=interpret))
-        if not _commit_round(inst, T, rem, start, gains, mu):
-            return start
-    return start
 
 
 # ---------------------------------------------------------------------------
@@ -196,7 +132,7 @@ def _climb_impl(mu: int, max_rounds: int, commit_k: int = _COMMIT_K,
             w_v = work[v]
             e = s + d_v
             # current-state legal bounds (commits earlier in this scan may
-            # have moved neighbours), exactly _commit_round's clamp
+            # have moved neighbours), the clamp of ``local_search.dyn_bounds``
             dlo = pred_lo_v(start, v)
             dhi = succ_hi_v(start, v) - d_v
             new_s = jnp.clip(s + best_delta[v], dlo, dhi)
@@ -302,9 +238,7 @@ def local_search_portfolio_multi(inst: Instance, T: int,
                                  unit_budgets: np.ndarray,
                                  starts: np.ndarray, mu: int = 10,
                                  max_rounds: int = 200,
-                                 interpret: bool | None = None,
                                  ctx: dict | None = None,
-                                 polish: bool = True,
                                  commit_k: int | None = None,
                                  adjacency: str | None = None,
                                  cancel=None) -> np.ndarray:
@@ -319,10 +253,6 @@ def local_search_portfolio_multi(inst: Instance, T: int,
     Args:
       unit_budgets: int [R, T] per-row effective budget timelines.
       starts:       int [R, N] one greedy schedule per row.
-      interpret:    unused: the device loop's gain oracle is picked by
-        backend (:func:`repro.kernels.gain_scan.gains_windows_auto` — the
-        jnp prefix-sum twin on CPU, the compiled Pallas kernel on TPU);
-        kept for climber-signature compatibility.
       ctx:          optional shared graph context (``ls_graph_context``;
         extra keys such as ``unit_budget`` are ignored).
       commit_k:     device commits per row per round (None = the module
@@ -417,54 +347,23 @@ def local_search_portfolio_multi(inst: Instance, T: int,
         rounds_hist.observe(int(r))
     starts = climbed[:R, :N].astype(np.int64)
 
-    if polish:
-        pad = mu
-        polish_rounds = 0
-        with obs.span("ls_polish", rows=int(R)) as polish_span:
-            for i in range(R):
-                rem_pad = np.zeros(T + 2 * pad, dtype=np.int64)
-                rem_pad[pad:pad + T] = unit_budgets[i] - work_timeline(
-                    inst, T, starts[i])
-                budget = max_rounds               # per-variant round budget
-                while budget > 0 and reference_round(inst, T, rem_pad, pad,
-                                                     starts[i], mu, ctx):
-                    budget -= 1
-                    polish_rounds += 1
-                    checkpoint(cancel)   # per-polish-round rung
-            polish_span.set(rounds=polish_rounds)
-        obs.registry().counter(
-            "ls_polish_rounds_total",
-            "sequential-reference polish rounds run after device climbs"
-        ).inc(polish_rounds)
+    pad = mu
+    polish_rounds = 0
+    with obs.span("ls_polish", rows=int(R)) as polish_span:
+        for i in range(R):
+            rem_pad = np.zeros(T + 2 * pad, dtype=np.int64)
+            rem_pad[pad:pad + T] = unit_budgets[i] - work_timeline(
+                inst, T, starts[i])
+            budget = max_rounds               # per-variant round budget
+            while budget > 0 and reference_round(inst, T, rem_pad, pad,
+                                                 starts[i], mu, ctx):
+                budget -= 1
+                polish_rounds += 1
+                checkpoint(cancel)   # per-polish-round rung
+        polish_span.set(rounds=polish_rounds)
+    obs.registry().counter(
+        "ls_polish_rounds_total",
+        "sequential-reference polish rounds run after device climbs"
+    ).inc(polish_rounds)
     return starts
 
-
-def local_search_portfolio(inst: Instance, profile: PowerProfile,
-                           starts: np.ndarray, mu: int = 10,
-                           max_rounds: int = 200,
-                           interpret: bool | None = None,
-                           ctx: dict | None = None,
-                           polish: bool = True,
-                           commit_k: int | None = None,
-                           adjacency: str | None = None,
-                           cancel=None) -> np.ndarray:
-    """Hill-climb a whole portfolio of schedules of one instance at once.
-
-    Args:
-      starts: int [V, N] — one greedy schedule per ``-LS`` variant.
-    Returns:
-      int64 [V, N] improved schedules (see
-      :func:`local_search_portfolio_multi`; this is the single-profile
-      slice of it).
-    """
-    starts = np.asarray(starts, dtype=np.int64)
-    V = starts.shape[0]
-    if ctx is not None and "unit_budget" in ctx:
-        unit = np.asarray(ctx["unit_budget"], dtype=np.int64)
-    else:
-        unit = profile.unit_budget(inst.idle_total).astype(np.int64)
-    budgets = np.broadcast_to(unit, (V, profile.T))
-    return local_search_portfolio_multi(
-        inst, profile.T, budgets, starts, mu=mu, max_rounds=max_rounds,
-        interpret=interpret, ctx=ctx, polish=polish, commit_k=commit_k,
-        adjacency=adjacency, cancel=cancel)

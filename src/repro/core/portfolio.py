@@ -44,8 +44,6 @@ Engines and entry points:
 * :func:`schedule_portfolio` / :func:`schedule_portfolio_multi` — legacy
   single-instance slices of the grid, kept as thin deprecation shims over
   :class:`repro.api.Planner` (property-tested bit-identical per engine).
-* :func:`portfolio_starts_batch` — shape-bucketed instance batching of the
-  greedy starts alone (the second vmap level, no assembly).
 
 :class:`repro.api.Planner` is the typed facade over this module:
 ``PlanRequest -> PlanResult`` with graph caching, ``engine="auto"``
@@ -287,9 +285,6 @@ def jit_entries_total() -> int:
     return sum(jax_hooks.jit_cache_entries().values())
 
 
-_jit_entries_total = jit_entries_total
-
-
 def _needed_combos(names) -> list[tuple[str, bool, bool]]:
     need = []
     for name in names:
@@ -489,7 +484,7 @@ def schedule_portfolio_grid(instances, profile_grid, platform: Platform,
             launch_span = obs.start_span(
                 "bucket_launch", bucket=f"{Npad}x{Tp}",
                 instances=len(idx), rows=n_rows)
-            misses0 = _jit_entries_total()
+            misses0 = jit_entries_total()
             # duplicate rows reuse the unique row's host-built tuple (the
             # launch keeps its bucket shape; only row prep is skipped —
             # the dedupe target shares the instance object, hence the
@@ -518,7 +513,7 @@ def schedule_portfolio_grid(instances, profile_grid, platform: Platform,
                     greedy_fanout_grid_jax(rows, devices=devices),
                     dtype=np.int64)
             finally:
-                misses = max(_jit_entries_total() - misses0, 0)
+                misses = max(jit_entries_total() - misses0, 0)
                 if misses:
                     obs.registry().counter(
                         "jax_jit_cache_misses_total",
@@ -691,57 +686,3 @@ def robust_pick(costs: np.ndarray, names) -> tuple[str, int]:
     worst = np.asarray(costs)[:, heur].max(axis=0)
     j = int(worst.argmin())
     return names[heur[j]], int(worst[j])
-
-
-# ---------------------------------------------------------------------------
-# Shape-bucketed instance batching (jax engine, second vmap level)
-# ---------------------------------------------------------------------------
-
-def _shape_key(prep: PreparedInstance) -> tuple:
-    from repro.core.greedy_jax import pad_dims
-    return pad_dims(prep.inst.num_tasks, prep.profile.T)
-
-
-def portfolio_starts_batch(preps: list[PreparedInstance],
-                           combos=_COMBOS) -> list[np.ndarray]:
-    """Greedy starts for a batch of instances x all variants on device.
-
-    Instances are grouped by padded shape bucket (:func:`repro.core
-    .greedy_jax.pad_dims`); each group runs as ONE doubly-vmapped jitted
-    call. Returns, aligned with ``preps``, int64 arrays of shape
-    [len(combos), N_i].
-    """
-    import jax.numpy as jnp
-
-    from repro.core.greedy_jax import _impl, pad_budget, pad_masks, \
-        pad_orders
-
-    results: list[np.ndarray | None] = [None] * len(preps)
-    groups: dict[tuple, list[int]] = {}
-    for i, p in enumerate(preps):
-        groups.setdefault(_shape_key(p), []).append(i)
-    for (_, Tp), idx in groups.items():
-        rows = []
-        for i in idx:
-            p = preps[i]
-            dur, work, lp, est_j, lst_j, tail = p.graph.shared()
-            if p.graph.lp_is_blocked:
-                raise TypeError(
-                    "portfolio_starts_batch batches dense-lp instances "
-                    "only; blocked-lp (big) instances go through "
-                    "greedy_fanout_grid_jax / schedule_portfolio_grid")
-            masks = pad_masks(np.stack(
-                [p.masks[r] for (_, _, r) in combos]), Tp)
-            orders = pad_orders(np.stack(
-                [p.graph.order_for(s, w) for (s, w, _) in combos]), tail)
-            rem0 = pad_budget(
-                p.profile.unit_budget(p.inst.idle_total), Tp)
-            rows.append((dur, work, lp, jnp.asarray(rem0),
-                         jnp.asarray(masks), est_j, lst_j,
-                         jnp.asarray(orders)))
-        stacked = tuple(jnp.stack([r[a] for r in rows])
-                        for a in range(8))
-        starts = np.asarray(_impl()["batch"](*stacked), dtype=np.int64)
-        for b, i in enumerate(idx):
-            results[i] = starts[b][:, :preps[i].inst.num_tasks]
-    return results

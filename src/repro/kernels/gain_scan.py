@@ -30,10 +30,10 @@ the same windows (``repro.kernels.backend.resolve_mode`` picks one):
   oracle of the device-resident climb on CPU; on TPU the climb routes
   through the compiled kernel (:func:`gains_windows_auto`).
 
-The jnp twin wins at small N (four prefix sums beat 2*mu+1 masked
-reductions until the kernel's tiling amortizes); the measured crossover
-vs the kernel is recorded in ``BENCH_portfolio.json`` under
-``sharded["gain_kernel"]`` (``make bench-smoke``).
+The device climb (:mod:`repro.core.local_search_jax`) runs
+:func:`gather_windows` then :func:`gains_windows_auto` inline in its jitted
+round loop, vmapped over its rows; :func:`gain_scan` is the same
+composition jitted for one row, the kernel tests' entry point.
 
 Gain identities (rem includes the task at its old position; the newly
 occupied region never overlaps the old window, so rem == rem-without-task
@@ -297,47 +297,3 @@ def gain_scan(rem, start, dur, work, lo, hi, *, mu: int = 10,
     return gains_windows_auto(win_s, win_e, work, dur, lo - start,
                               hi - start, mu=mu, interpret=interpret)
 
-
-def _gain_scan_windows(win_s, win_e, start, dur, work, lo, hi, *, mu,
-                       interpret):
-    """Legacy absolute-bounds spelling of :func:`gains_windows_auto`."""
-    return gains_windows_auto(win_s, win_e, work, dur, lo - start,
-                              hi - start, mu=mu, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("mu", "interpret"))
-def gain_scan_batched(rem, start, dur, work, lo, hi, *, mu: int = 10,
-                      interpret: bool | None = None):
-    """Gains for a whole portfolio of schedules in ONE kernel launch.
-
-    The kernel body is per-task-independent once the timeline windows are
-    gathered, so a batch of B schedules (portfolio variants, ensemble
-    profiles, or both flattened) becomes a (B*N)-task problem: windows are
-    gathered per (batch row, task) from that row's timeline, and a single
-    launch covers all rows.
-
-    Args:
-      rem:  f32[B, T] per-row remaining-budget timelines.
-      start, lo, hi: f32[B, N] per-row schedules / legal bounds.
-      dur, work: f32[N], shared across rows (same instance).
-      mu: max shift.
-      interpret: None = auto (see :func:`gain_scan`).
-    Returns:
-      f32[B, N, 2*mu+1].
-    """
-    B, n = start.shape
-    win = jnp.arange(W)[None, None, :] - mu                   # (1, 1, W)
-    rem_pad = jnp.pad(rem, ((0, 0), (W, W)))
-    t_total = rem.shape[1]
-    s_i = start.astype(jnp.int32)
-    e_i = (start + dur[None, :]).astype(jnp.int32)
-    idx_s = jnp.clip(s_i[:, :, None] + win + W, 0, t_total + 2 * W - 1)
-    idx_e = jnp.clip(e_i[:, :, None] + win + W, 0, t_total + 2 * W - 1)
-    win_s = jnp.take_along_axis(rem_pad[:, None, :], idx_s, axis=2)
-    win_e = jnp.take_along_axis(rem_pad[:, None, :], idx_e, axis=2)
-
-    flat = _gain_scan_windows(
-        win_s.reshape(B * n, W), win_e.reshape(B * n, W),
-        start.reshape(B * n), jnp.tile(dur, B), jnp.tile(work, B),
-        lo.reshape(B * n), hi.reshape(B * n), mu=mu, interpret=interpret)
-    return flat.reshape(B, n, 2 * mu + 1)

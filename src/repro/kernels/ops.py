@@ -1,4 +1,4 @@
-"""Public jit'd entry points for the Pallas kernels.
+"""Public entry point of the carbon-cost Pallas kernel.
 
 ``interpret=None`` auto-detects the backend (interpret on CPU, compile on
 TPU — see :mod:`repro.kernels.backend`); pass an explicit bool to override.
@@ -8,7 +8,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.kernels.carbon_cost import deficit_timeline
-from repro.kernels.gain_scan import gain_scan, gain_scan_batched
 
 
 def carbon_cost(starts, durs, works, g_eff, *, interpret: bool | None = None):
@@ -19,26 +18,3 @@ def carbon_cost(starts, durs, works, g_eff, *, interpret: bool | None = None):
         starts, ends, jnp.asarray(works, jnp.float32),
         jnp.asarray(g_eff, jnp.float32), interpret=interpret).sum()
 
-
-def ls_gains(rem, start, dur, work, lo, hi, *, mu: int = 10,
-             interpret: bool | None = None):
-    """Local-search gain matrix f32[N, 2*mu+1] (illegal moves = -1e30)."""
-    return gain_scan(
-        jnp.asarray(rem, jnp.float32), jnp.asarray(start, jnp.float32),
-        jnp.asarray(dur, jnp.float32), jnp.asarray(work, jnp.float32),
-        jnp.asarray(lo, jnp.float32), jnp.asarray(hi, jnp.float32),
-        mu=mu, interpret=interpret)
-
-
-def ls_gains_batched(rem, start, dur, work, lo, hi, *, mu: int = 10,
-                     interpret: bool | None = None):
-    """Batched gain matrices f32[B, N, 2*mu+1] in ONE kernel launch.
-
-    ``rem``/``start``/``lo``/``hi`` carry a leading batch axis [B, ...]
-    (one row per portfolio variant); ``dur``/``work`` are shared [N].
-    """
-    return gain_scan_batched(
-        jnp.asarray(rem, jnp.float32), jnp.asarray(start, jnp.float32),
-        jnp.asarray(dur, jnp.float32), jnp.asarray(work, jnp.float32),
-        jnp.asarray(lo, jnp.float32), jnp.asarray(hi, jnp.float32),
-        mu=mu, interpret=interpret)

@@ -85,26 +85,28 @@ def install(registry: MetricsRegistry) -> bool:
 def jit_cache_entries() -> Dict[str, int]:
     """Compiled-signature counts for the engine's jit launchers.
 
-    Keys: ``greedy.<name>`` / ``blocked.<name>`` per jitted function in
-    the (already-built) implementation bundles, plus ``climb.variants``
-    for the local-search climb lru (distinct padded signatures). Probes
-    that would *trigger* compilation are skipped.
+    Keys: ``greedy.grid`` (the single-device grid launch),
+    ``greedy.grid_sharded.<ndev>`` per sharded grid built, and
+    ``blocked.chunk`` (the blocked form's chunk program), each once built,
+    plus ``climb.variants`` for the local-search climb lru (distinct
+    padded signatures). Probes that would *trigger* compilation are
+    skipped.
     """
     out: Dict[str, int] = {}
     try:
         from repro.core import greedy_jax
+        launchers = {}
         if greedy_jax._impl.cache_info().currsize:
-            for name, fn in greedy_jax._impl().items():
-                try:
-                    out[f"greedy.{name}"] = int(fn._cache_size())
-                except Exception:
-                    pass
+            launchers["greedy.grid"] = greedy_jax._impl()
+        for ndev, fn in list(greedy_jax._sharded_grids.items()):
+            launchers[f"greedy.grid_sharded.{ndev}"] = fn
         if greedy_jax._blocked_impl.cache_info().currsize:
-            for name, fn in greedy_jax._blocked_impl().items():
-                try:
-                    out[f"blocked.{name}"] = int(fn._cache_size())
-                except Exception:
-                    pass
+            launchers["blocked.chunk"] = greedy_jax._blocked_impl()
+        for name, fn in launchers.items():
+            try:
+                out[name] = int(fn._cache_size())
+            except Exception:
+                pass
     except Exception:
         pass
     try:

@@ -46,6 +46,36 @@ def medium_instance(small_platform):
     return build_instance(wf, mp, small_platform)
 
 
+@pytest.fixture(scope="session")
+def climb_gains():
+    """The device climb's gain call over a batch of rows: every row's
+    timeline windows gathered (``vmap`` of ``gather_windows``), then all
+    rows' tasks flattened into ONE ``gains_windows_auto`` call. Takes
+    ``gain_scan``'s arguments with a leading row axis on ``rem``,
+    ``start``, ``lo`` and ``hi`` (absolute bounds); returns f32 numpy
+    [B, N, 2*mu+1]."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.gain_scan import gains_windows_auto, gather_windows
+
+    def gains(rem, start, dur, work, lo, hi, *, mu, interpret=None):
+        rem, start, dur, work, lo, hi = (
+            jnp.asarray(a, jnp.float32)
+            for a in (rem, start, dur, work, lo, hi))
+        B, n = start.shape
+        win_s, win_e = jax.vmap(
+            lambda r, s: gather_windows(r, s, dur, mu=mu))(rem, start)
+        flat = gains_windows_auto(
+            win_s.reshape(B * n, -1), win_e.reshape(B * n, -1),
+            jnp.tile(work, B), jnp.tile(dur, B),
+            (lo - start).reshape(B * n), (hi - start).reshape(B * n),
+            mu=mu, interpret=interpret)
+        return np.asarray(flat).reshape(B, n, 2 * mu + 1)
+
+    return gains
+
+
 def random_instance(n_tasks=24, seed=0, platform=None, kind="atacseq"):
     platform = platform or make_cluster(1, seed=seed)
     wf = make_workflow(kind, max(n_tasks // 12, 1), seed=seed)

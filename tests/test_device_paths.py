@@ -11,10 +11,12 @@ from repro.core import (
     generate_profile,
     heft_mapping,
     schedule_cost,
+    schedule_portfolio_grid,
+    schedule_reference,
     validate_schedule,
 )
+from repro.core.cawosched import Variant
 from repro.core.greedy import greedy_schedule
-from repro.core.greedy_jax import greedy_schedule_jax
 from repro.workflows import make_workflow
 from repro.workflows.dot_io import load_dot, save_dot
 
@@ -33,13 +35,21 @@ def test_device_greedy_matches_reference_exactly(seed, kind, scen, sc, wt,
     inst = build_instance(wf, heft_mapping(wf, plat), plat)
     T = deadline_from_asap(inst, 1.5)
     prof = generate_profile(scen, T, plat, J=12, seed=seed)
+    name = Variant(sc, wt, rf, ls=False).name
     a = greedy_schedule(inst, prof, plat, score=sc, weighted=wt, refined=rf)
-    b = np.asarray(greedy_schedule_jax(inst, prof, plat, score=sc,
-                                       weighted=wt, refined=rf),
-                   dtype=np.int64)
+    (cells,) = schedule_portfolio_grid([inst], [[prof]], plat,
+                                       variants=[name], engine="jax",
+                                       validate=True)
+    b = cells[0][name].start
     assert (a == b).all()
     validate_schedule(inst, prof, b)
     assert schedule_cost(inst, prof, a) == schedule_cost(inst, prof, b)
+    # the numpy engine and the sequential reference land on the same row
+    (ref_cells,) = schedule_portfolio_grid([inst], [[prof]], plat,
+                                           variants=[name], engine="numpy")
+    assert (ref_cells[0][name].start == b).all()
+    ref = schedule_reference(inst, prof, plat, name)
+    assert (ref.start == b).all() and ref.cost == cells[0][name].cost
 
 
 def test_dot_roundtrip(tmp_path):

@@ -16,7 +16,7 @@ from repro.core import (
 from repro.core.carbon import work_timeline
 from repro.kernels.carbon_cost import deficit_timeline
 from repro.kernels.gain_scan import gain_scan
-from repro.kernels.ops import carbon_cost, ls_gains
+from repro.kernels.ops import carbon_cost
 from repro.kernels.ref import deficit_timeline_ref, gain_scan_ref
 from repro.workflows import make_workflow
 
@@ -130,8 +130,9 @@ def test_gain_kernel_on_real_instance():
     N = inst.num_tasks
     lo = np.zeros(N)
     hi = np.full(N, T) - inst.dur
-    gains = np.asarray(ls_gains(rem, start, inst.dur, inst.task_work,
-                                lo, hi, mu=6))
+    gains = np.asarray(gain_scan(
+        *(jnp.asarray(a, jnp.float32)
+          for a in (rem, start, inst.dur, inst.task_work, lo, hi)), mu=6))
     base = schedule_cost(inst, prof, start)
     # applying any positive-gain single move must reduce the exact cost by
     # exactly that gain
@@ -198,9 +199,7 @@ class TestGainKernelBitIdentity:
         assert (twin[:, mu] == -1e30).all()  # delta=0 always illegal
 
     @pytest.mark.parametrize("mu", [3, 17])
-    def test_batched_bit_identity(self, mu):
-        from repro.kernels.gain_scan import gain_scan_batched
-
+    def test_batched_bit_identity(self, mu, climb_gains):
         rng = np.random.default_rng(mu)
         B, n, t = 3, 40, 256
         rem = rng.integers(-9, 9, (B, t)).astype(np.float32)
@@ -209,13 +208,9 @@ class TestGainKernelBitIdentity:
         start = rng.integers(0, t - 10, (B, n)).astype(np.float32)
         lo = np.maximum(start - 20, 0).astype(np.float32)
         hi = (start + 20).astype(np.float32)
-        args = tuple(jnp.asarray(a) for a in (rem, start, dur, work, lo, hi))
-        twin = np.asarray(gain_scan_batched(args[0], args[1], args[2],
-                                            args[3], args[4], args[5],
-                                            mu=mu, interpret=None))
-        kern = np.asarray(gain_scan_batched(args[0], args[1], args[2],
-                                            args[3], args[4], args[5],
-                                            mu=mu, interpret=True))
+        args = (rem, start, dur, work, lo, hi)
+        twin = climb_gains(*args, mu=mu, interpret=None)
+        kern = climb_gains(*args, mu=mu, interpret=True)
         assert twin.shape == (B, n, 2 * mu + 1)
         assert (twin == kern).all()
 

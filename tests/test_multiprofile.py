@@ -138,8 +138,8 @@ def test_longest_path_matrix_matches_worklist_relaxation():
 
 
 @pytest.mark.device
-def test_gains_jnp_twin_matches_pallas_interpreter():
-    from repro.kernels.ops import ls_gains, ls_gains_batched
+def test_gains_jnp_twin_matches_pallas_interpreter(climb_gains):
+    from repro.kernels.gain_scan import gain_scan
 
     rng = np.random.default_rng(2)
     N, T, mu = 70, 200, 9
@@ -150,19 +150,19 @@ def test_gains_jnp_twin_matches_pallas_interpreter():
     lo = np.maximum(start - rng.integers(0, mu + 4, N), 0).astype(np.float32)
     hi = np.minimum(start + rng.integers(0, mu + 4, N),
                     T - dur).astype(np.float32)
-    jnp_path = np.asarray(ls_gains(rem, start, dur, work, lo, hi, mu=mu,
-                                   interpret=None))
-    pallas = np.asarray(ls_gains(rem, start, dur, work, lo, hi, mu=mu,
-                                 interpret=True))
+    jnp_path = np.asarray(gain_scan(rem, start, dur, work, lo, hi, mu=mu,
+                                    interpret=None))
+    pallas = np.asarray(gain_scan(rem, start, dur, work, lo, hi, mu=mu,
+                                  interpret=True))
     np.testing.assert_array_equal(jnp_path, pallas)
     # batched twin
     rem2 = np.stack([rem, np.roll(rem, 11)])
     start2 = np.stack([start, start])
     lo2, hi2 = np.stack([lo, lo]), np.stack([hi, hi])
-    a = np.asarray(ls_gains_batched(rem2, start2, dur, work, lo2, hi2,
-                                    mu=mu, interpret=None))
-    b = np.asarray(ls_gains_batched(rem2, start2, dur, work, lo2, hi2,
-                                    mu=mu, interpret=True))
+    a = climb_gains(rem2, start2, dur, work, lo2, hi2, mu=mu,
+                    interpret=None)
+    b = climb_gains(rem2, start2, dur, work, lo2, hi2, mu=mu,
+                    interpret=True)
     np.testing.assert_array_equal(a, b)
 
 

@@ -377,6 +377,11 @@ def test_jax_hooks_snapshot_shape():
     assert set(snap) >= {"compile_events", "compile_seconds",
                          "jit_cache_entries", "live_arrays"}
     assert isinstance(snap["jit_cache_entries"], dict)
+    # after a jax-engine plan the single grid launcher is counted
+    planner, req = _jax_plan()
+    planner.plan(req)
+    entries = jax_hooks.snapshot(reg)["jit_cache_entries"]
+    assert entries["greedy.grid"] >= 1
 
 
 # --- host stages of the jax engine and their profiler mirror ---------------

@@ -268,7 +268,7 @@ def test_nondefault_commit_k_still_matches_sequential_reference():
     """ROADMAP open item: the device climb's commit width is tunable; any
     K must land on a state the sequential reference cannot improve."""
     from repro.core.greedy import greedy_schedule
-    from repro.core.local_search_jax import local_search_portfolio
+    from repro.core.local_search_jax import local_search_portfolio_multi
 
     plat, inst, prof = _setup(samples=3, seed=4, factor=2.0, scenario="S1")
     combos = (("press", False, True), ("slack", True, False),
@@ -276,9 +276,11 @@ def test_nondefault_commit_k_still_matches_sequential_reference():
     stack = np.stack([greedy_schedule(inst, prof, plat, s, w, r)
                       for (s, w, r) in combos])
     base = [schedule_cost(inst, prof, st) for st in stack]
+    budgets = np.broadcast_to(prof.unit_budget(inst.idle_total),
+                              (len(stack), prof.T))
     for kk in (1, 4, 96):
-        improved = local_search_portfolio(inst, prof, stack, mu=10,
-                                          commit_k=kk)
+        improved = local_search_portfolio_multi(
+            inst, prof.T, budgets, stack, mu=10, commit_k=kk)
         for i in range(len(combos)):
             validate_schedule(inst, prof, improved[i])
             assert schedule_cost(inst, prof, improved[i]) <= base[i]

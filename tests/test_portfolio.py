@@ -22,7 +22,7 @@ from repro.core.greedy import (
     greedy_schedule_segments,
     segment_state,
 )
-from repro.core.local_search_jax import local_search_portfolio
+from repro.core.local_search_jax import local_search_portfolio_multi
 from repro.core.scores import task_order
 from repro.core.subdivide import candidate_mask
 from repro.workflows import make_workflow
@@ -145,15 +145,16 @@ def test_endpoint_rule_on_overrunning_task():
 @pytest.mark.device
 def test_device_greedy_matches_numpy_at_tight_deadline():
     """Regression companion: the jax scan uses the numpy endpoint rule."""
-    from repro.core.greedy_jax import greedy_schedule_jax
+    from repro.core import schedule_portfolio_grid
 
     for seed, kind in ((0, "eager"), (6, "bacass")):
         plat, inst, prof = _setup(kind=kind, seed=seed, factor=1.0,
                                   scenario="S2")
         a = greedy_schedule(inst, prof, plat, "press", True, False)
-        b = np.asarray(greedy_schedule_jax(inst, prof, plat, "press", True,
-                                           False))
-        assert (a == b.astype(np.int64)).all()
+        (cells,) = schedule_portfolio_grid([inst], [[prof]], plat,
+                                           variants=["pressW"], engine="jax",
+                                           validate=True)
+        assert (a == cells[0]["pressW"].start).all()
 
 
 @pytest.mark.device
@@ -169,25 +170,40 @@ def test_jax_engine_greedy_rows_match_numpy():
 
 @pytest.mark.device
 def test_instance_batched_fanout_matches_reference():
-    """Two same-shape instances (same workflow/platform, different profile
-    budgets) ride one doubly-vmapped call; every (instance, combo) row must
-    equal the numpy reference greedy."""
-    from repro.core.portfolio import _COMBOS, portfolio_starts_batch
+    """Instances in two shape buckets, two of them sharing one (same
+    workflow/platform, different profile budgets), ride one jax-engine
+    grid call; every (instance, profile, combo) row must equal the numpy
+    engine's and the numpy reference greedy."""
+    from repro.core import schedule_portfolio_grid
+    from repro.core.cawosched import Variant
+    from repro.core.greedy_jax import pad_dims
+    from repro.core.portfolio import _COMBOS
 
     plat = make_cluster(1, seed=3)
-    wf = make_workflow("eager", 2, seed=3)
-    inst = build_instance(wf, heft_mapping(wf, plat), plat)
-    T = deadline_from_asap(inst, 1.5)
-    profs = [generate_profile(s, T, plat, J=12, seed=3) for s in ("S1", "S4")]
-    preps = [prepare_instance(inst, p, plat) for p in profs]
+    instances, grid = [], []
+    for kind, samples in (("eager", 2), ("eager", 2), ("bacass", 6)):
+        wf = make_workflow(kind, samples, seed=3)
+        inst = build_instance(wf, heft_mapping(wf, plat), plat)
+        T = deadline_from_asap(inst, 1.5)
+        instances.append(inst)
+        grid.append([generate_profile(s, T, plat, J=12,
+                                      seed=3 + len(instances))
+                     for s in ("S1", "S4")])
+    buckets = {pad_dims(i.num_tasks, g[0].T)
+               for i, g in zip(instances, grid)}
+    assert len(buckets) == 2
     combos = _COMBOS[:3]
-    starts = portfolio_starts_batch(preps, combos=combos)
-    assert len(starts) == 2
-    for p, st in zip(preps, starts):
-        assert st.shape == (len(combos), inst.num_tasks)
-        for i, (sc, wt, rf) in enumerate(combos):
-            ref = greedy_schedule(inst, p.profile, plat, sc, wt, rf)
-            assert (st[i] == ref).all(), (sc, wt, rf)
+    names = [Variant(sc, wt, rf, ls=False).name for sc, wt, rf in combos]
+    got = schedule_portfolio_grid(instances, grid, plat, variants=names,
+                                  engine="jax")
+    want = schedule_portfolio_grid(instances, grid, plat, variants=names,
+                                   engine="numpy")
+    for inst, profs, row, ref_row in zip(instances, grid, got, want):
+        for prof, cell, ref_cell in zip(profs, row, ref_row):
+            for name, (sc, wt, rf) in zip(names, combos):
+                ref = greedy_schedule(inst, prof, plat, sc, wt, rf)
+                assert (cell[name].start == ref).all(), name
+                assert (cell[name].start == ref_cell[name].start).all()
 
 
 @pytest.mark.device
@@ -210,15 +226,20 @@ def test_batched_portfolio_local_search_monotone_and_valid():
     stack = np.stack([greedy_schedule(inst, prof, plat, s, w, r)
                       for (s, w, r) in combos])
     base = [schedule_cost(inst, prof, st) for st in stack]
-    improved = local_search_portfolio(inst, prof, stack, mu=10)
+    budgets = np.broadcast_to(prof.unit_budget(inst.idle_total),
+                              (len(stack), prof.T))
+    improved = local_search_portfolio_multi(inst, prof.T, budgets, stack,
+                                            mu=10)
     for i in range(len(combos)):
         validate_schedule(inst, prof, improved[i])
         assert schedule_cost(inst, prof, improved[i]) <= base[i]
 
 
 @pytest.mark.device
-def test_gain_scan_batched_matches_rows():
-    from repro.kernels.ops import ls_gains, ls_gains_batched
+def test_gain_scan_batched_matches_rows(climb_gains):
+    """The climb's batched gain call equals ``gain_scan`` row for row, on
+    the jnp twin and under the Pallas interpreter."""
+    from repro.kernels.gain_scan import gain_scan
 
     rng = np.random.default_rng(0)
     B, N, T, mu = 3, 40, 160, 6
@@ -231,11 +252,13 @@ def test_gain_scan_batched_matches_rows():
         .astype(np.float32)
     hi = np.minimum(start + rng.integers(0, mu + 3, (B, N)), T - dur) \
         .astype(np.float32)
-    got = np.asarray(ls_gains_batched(rem, start, dur, work, lo, hi, mu=mu))
-    for b in range(B):
-        want = np.asarray(ls_gains(rem[b], start[b], dur, work, lo[b],
-                                   hi[b], mu=mu))
-        np.testing.assert_allclose(got[b], want, rtol=0, atol=0)
+    for interpret in (None, True):
+        got = climb_gains(rem, start, dur, work, lo, hi, mu=mu,
+                          interpret=interpret)
+        for b in range(B):
+            want = np.asarray(gain_scan(rem[b], start[b], dur, work, lo[b],
+                                        hi[b], mu=mu, interpret=interpret))
+            np.testing.assert_allclose(got[b], want, rtol=0, atol=0)
 
 
 def test_interpret_autodetect_resolves_cpu():

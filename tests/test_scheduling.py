@@ -20,7 +20,7 @@ from repro.core import (
 from repro.core.estlst import est_lst_jnp
 from repro.core.greedy import greedy_schedule
 from repro.core.local_search import local_search, move_gain, apply_move, timeline_cost
-from repro.core.local_search_jax import local_search_batched
+from repro.core.local_search_jax import local_search_portfolio_multi
 from repro.workflows import make_workflow
 
 
@@ -77,7 +77,9 @@ def test_batched_ls_matches_reference_quality():
     g = greedy_schedule(inst, prof, plat, score="press", refined=True)
     c0 = schedule_cost(inst, prof, g)
     ref = schedule_cost(inst, prof, local_search(inst, prof, plat, g))
-    bat = schedule_cost(inst, prof, local_search_batched(inst, prof, g))
+    (climbed,) = local_search_portfolio_multi(
+        inst, prof.T, prof.unit_budget(inst.idle_total)[None], g[None])
+    bat = schedule_cost(inst, prof, climbed)
     assert bat <= c0
     # both hill climbers should land in the same ballpark
     assert bat <= max(1.15 * ref, ref + 50)

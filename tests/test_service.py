@@ -8,6 +8,7 @@ marker (`make test-chaos`); this file keeps the acceptance-critical
 behaviours in the default tier-1 gate.
 """
 import os
+import threading
 import time
 
 import numpy as np
@@ -224,16 +225,25 @@ def test_service_device_oom_retries_on_blocked_lp_planner():
 # --- priority admission + aging --------------------------------------------
 
 def _completion_order(named_tickets, timeout=60.0):
-    order, pending = [], dict(named_tickets)
-    deadline = time.monotonic() + timeout
-    while pending and time.monotonic() < deadline:
-        for name, t in list(pending.items()):
-            if t.done():
+    """The order in which the tickets resolve: each is recorded by a
+    done-callback on its future, in the thread that resolves it, so two
+    tickets that finish between two looks keep their true order."""
+    order, resolved = [], threading.Condition()
+
+    def record(name):
+        def done(_fut):
+            with resolved:
                 order.append(name)
-                del pending[name]
-        time.sleep(0.005)
-    assert not pending, f"tickets never resolved: {sorted(pending)}"
-    return order
+                resolved.notify_all()
+        return done
+
+    for name, t in named_tickets.items():
+        t._fut.add_done_callback(record(name))
+    with resolved:
+        resolved.wait_for(lambda: len(order) == len(named_tickets), timeout)
+        missing = sorted(set(named_tickets) - set(order))
+        assert not missing, f"tickets never resolved: {missing}"
+        return list(order)
 
 
 def test_priority_admission_serves_earliest_deadline_first():

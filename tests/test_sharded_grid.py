@@ -97,6 +97,9 @@ def test_sharded_grid_bitwise_identical(grid_case, platform, ndev):
     for key in base:
         assert np.array_equal(base[key][0], shard[key][0]), key
         assert base[key][1] == shard[key][1], key
+    # the sharded launcher's compiles are counted under its own key
+    from repro.obs.jax_hooks import jit_cache_entries
+    assert jit_cache_entries()[f"greedy.grid_sharded.{ndev}"] >= 1
 
 
 def test_planner_devices_knob_bitwise(grid_case, platform):

@@ -94,7 +94,7 @@ def test_greedy_grid_compiles(one_chip):
             _spec(one_chip, (inst, n), i32),                 # est0
             _spec(one_chip, (inst, n), i32),                 # lst0
             _spec(one_chip, (inst, COMBOS, n), i32))         # orders
-    compiled = _impl()["grid"].lower(*args).compile()
+    compiled = _impl().lower(*args).compile()
     assert compiled.memory_analysis() is not None
 
 
@@ -114,7 +114,7 @@ def test_blocked_chunk_program_compiles(one_chip, monkeypatch):
              _spec(one_chip, (PROFILES, COMBOS, t + 1), jnp.bool_),  # mask
              *(_spec(one_chip, (PROFILES, COMBOS, n), i32),) * 3)
     table = (_spec(one_chip, (levels, edges), i32),) * 3
-    compiled = _blocked_impl()["multi"].lower(
+    compiled = _blocked_impl().lower(
         _spec(one_chip, (n,), i32), _spec(one_chip, (n,), i32), *state,
         _spec(one_chip, (COMBOS, steps), i32), table, table).compile()
     hlo = compiled.as_text()
