@@ -18,11 +18,13 @@ scans, so the program compiles in a fraction of the old level-relax
 formulation's time and executes orders of magnitude faster on CPU.
 
 One launch serves every request shape: :func:`greedy_fanout_grid_jax`
-runs the scan vmapped over variants (score orders and candidate masks) x
-profiles (an ensemble's budget timelines and masks) x instances (the
-stacked rows of one shape bucket) as ONE jitted program (:func:`_impl`),
-or ``shard_map``-ed over the instance axis across devices
-(:func:`_grid_sharded_impl`); both wrap the same traced function.
+runs one scan over every (instance, profile, variant) row of a shape
+bucket — the stacked instances, an ensemble's budget timelines and masks,
+the score orders — as ONE jitted program (:func:`_impl`), or
+``shard_map``-ed over the instance axis across devices
+(:func:`_grid_sharded_impl`); both wrap the same traced function. The
+rows lie on one flat axis, so the scan's est/lst state is [rows, tasks]
+with the tasks on the lanes (:func:`_placement_step`).
 
 Retracing discipline: all inputs are padded to shape buckets
 (:func:`pad_dims` — N to multiples of 128, T to multiples of 256) before
@@ -314,69 +316,126 @@ def pad_dims(N: int, T: int) -> tuple[int, int]:
     return _bucket_up(N, N_BUCKET), _bucket_up(T, T_BUCKET)
 
 
-def _placement_step(jnp, dur, work):
-    """THE §5.2 placement step, shared by the dense scan (which looks
-    ``row``/``col`` up in the resident lp matrix) and the chunked blocked
-    scan (which receives them as scan inputs) — one body, so the
-    blocked==dense bit-identity contract cannot drift."""
-    big = jnp.int32(np.iinfo(np.int32).max // 4)
+def _placement_step(jnp, lax):
+    """THE §5.2 placement step, one step of every row at once; shared by
+    the dense scan (which looks the lp rows and columns up in the resident
+    matrix) and the chunked blocked scan (which receives them as scan
+    inputs) — one body, so the blocked==dense bit-identity contract cannot
+    drift.
 
-    def step(state, v, row, col):
-        rem, mask, est, lst, start = state
-        T = rem.shape[0]
-        tgrid = jnp.arange(T, dtype=jnp.int32)
-        feas = mask[:-1] & (tgrid >= est[v]) & (tgrid <= lst[v])
-        any_f = feas.any()
+    The rows lie on one flat leading axis R = G x P x V, group-major (an
+    instance of the dense grid, or the one instance of a chunk), then
+    profile, then score order. The state is ``(rem [R, Tp], mask
+    [R, Tp+1], est [R, Np], lst [R, Np])``: time and tasks are minor, so
+    they fall on the lanes. A step takes ``x``, each row's task, its
+    duration and its work, three [R] (:func:`_step_inputs`), and the lp
+    row and column [G, V, Np] of each group's and order's task, shared by
+    the P profiles (broadcast, never copied); it returns the new state and
+    each row's start [R].
+    """
+    big = jnp.int32(np.iinfo(np.int32).max // 4)
+    # a[r, v_r] for each row r; v_r is a padded task id, in bounds by
+    # construction (no clamp)
+    pick = functools.partial(
+        lax.gather, dimension_numbers=lax.GatherDimensionNumbers(
+            offset_dims=(), collapsed_slice_dims=(0, 1),
+            start_index_map=(0, 1)),
+        slice_sizes=(1, 1), mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+    def step(state, x, row, col):
+        rem, mask, est, lst = state
+        P = rem.shape[0] // (row.shape[0] * row.shape[1])
+        v, dv, wv = (a[:, None] for a in x)
+        at = jnp.concatenate(
+            [lax.broadcasted_iota(jnp.int32, v.shape, 0), v], axis=1)
+        ev, lv = (pick(a, at)[:, None] for a in (est, lst))
+        tgrid = lax.broadcasted_iota(jnp.int32, rem.shape, 1)
+        feas = mask[:, :-1] & (tgrid >= ev) & (tgrid <= lv)
+        any_f = feas.any(axis=1, keepdims=True)
         val = jnp.where(feas, rem, -big)
-        s = jnp.where(any_f, jnp.argmax(val).astype(jnp.int32),
-                      est[v].astype(jnp.int32))
-        e = s + dur[v]
+        s = jnp.where(any_f,
+                      jnp.argmax(val, axis=1, keepdims=True).astype(
+                          jnp.int32), ev)
+        e = s + dv
         run = (tgrid >= s) & (tgrid < e)
-        rem = rem - jnp.where(run, work[v], 0).astype(rem.dtype)
-        mask = mask.at[s].set(True)
-        # numpy endpoint rule: e splits an interval only when e <= T; an
-        # overrunning task must not spuriously mark T a candidate point.
-        eidx = jnp.minimum(e, T)
-        mask = mask.at[eidx].set(mask[eidx] | (e <= T))
-        est = jnp.maximum(est, s + row)
-        lst = jnp.minimum(lst, s - col)
-        start = start.at[v].set(s)
-        return (rem, mask, est, lst, start)
+        rem = rem - jnp.where(run, wv, 0).astype(rem.dtype)
+        # s and e become candidate points; the compare against the mask's
+        # own lanes is the numpy endpoint rule: e splits an interval only
+        # when e <= T, so an overrunning task marks nothing
+        points = lax.broadcasted_iota(jnp.int32, mask.shape, 1)
+        mask = mask | (points == s) | (points == e)
+        est = jnp.maximum(est, s + _rows(jnp, row, P))
+        lst = jnp.minimum(lst, s - _rows(jnp, col, P))
+        return (rem, mask, est, lst), s[:, 0]
 
     return step
 
 
+def _rows(jnp, a, P):
+    """[G, V, ...] -> [G * P * V, ...]: each group's and order's values
+    repeated for the P profiles, in the scan's row order."""
+    shape = a.shape[:1] + (P,) + a.shape[1:]
+    return jnp.broadcast_to(a[:, None], shape).reshape((-1,) + a.shape[2:])
+
+
+def _step_inputs(jnp, dur, work, order, P):
+    """The per-row scan inputs of :func:`_placement_step`, built once per
+    scan: three int32 [S, R] for score orders ``order`` [G, V, S] over
+    tasks with ``dur``, ``work`` [G, Np] — step k's task of each row, its
+    duration and its work."""
+    return tuple(_rows(jnp, a, P).T for a in (
+        order, *(jnp.take_along_axis(b[:, None], order, axis=2)
+                 for b in (dur, work))))
+
+
+def _place_starts(jnp, start, order, s):
+    """Scatter one scan's starts into ``start`` [R, Np]: ``s`` [S, R]
+    holds each step's start per row, placed at the row's task of step k,
+    ``order`` [G, V, S] (a score order visits each task once)."""
+    G, V, _ = order.shape
+    r = jnp.arange(start.shape[0])[:, None]
+    return start.at[r, _rows(jnp, order, start.shape[0] // (G * V))].set(s.T)
+
+
 @functools.lru_cache(maxsize=1)
 def _grid_fn():
-    """The unjitted grid launcher: the §5.2 scan vmapped over variants,
-    then profiles, then instances.
+    """The unjitted grid launcher: the §5.2 scan over every (instance,
+    profile, variant) row at once, the rows flattened onto one axis.
 
     Shared by :func:`_impl` (which jits it) and :func:`_grid_sharded_impl`
     (which wraps it in a ``shard_map`` before jitting), so both launch
     paths trace the SAME closure and stay bit-identical by construction.
     """
-    import jax
     import jax.numpy as jnp
     from jax import lax
 
     def greedy_scan(dur, work, lp, rem0, mask0, est0, lst0, order):
-        """One variant's §5.2 greedy over precomputed inputs (vmappable)."""
-        core = _placement_step(jnp, dur, work)
+        """Args: dur/work/est0/lst0 [I, Np]; lp [I, Np, Np]; rem0
+        [I, P, Tp]; mask0 [I, P, V, Tp+1]; order [I, V, Np]. Returns int32
+        [I, P, V, Np] start times."""
+        I, P, V, T1 = mask0.shape
+        Np = dur.shape[1]
+        R = I * P * V
+        core = _placement_step(jnp, lax)
+        inst = jnp.arange(I)[:, None]
+        lpt = jnp.swapaxes(lp, 1, 2)
 
-        def step(state, v):
-            return core(state, v, lp[v], lp[:, v]), None
+        def step(state, xs):
+            x, v = xs
+            return core(state, x, lp[inst, v], lpt[inst, v])
 
-        N = est0.shape[0]
-        state0 = (rem0, mask0, est0, lst0, jnp.zeros(N, jnp.int32))
-        (_, _, _, _, start), _ = lax.scan(step, state0, order)
-        return start
+        state0 = (jnp.broadcast_to(rem0[:, :, None], (I, P, V, T1 - 1)
+                                   ).reshape(R, T1 - 1),
+                  mask0.reshape(R, T1),
+                  *(_rows(jnp, jnp.broadcast_to(a[:, None], order.shape), P)
+                    for a in (est0, lst0)))
+        _, s = lax.scan(step, state0,
+                        (_step_inputs(jnp, dur, work, order, P),
+                         jnp.moveaxis(order, 2, 0)))
+        start = _place_starts(jnp, jnp.zeros((R, Np), jnp.int32), order, s)
+        return start.reshape(I, P, V, Np)
 
-    # axis spec per argument: (dur, work, lp, rem0, mask0, est0, lst0, order)
-    variant_axes = (None, None, None, None, 0, None, None, 0)
-    profile_axes = (None, None, None, 0, 0, None, None, None)
-    fanout = jax.vmap(greedy_scan, in_axes=variant_axes)
-    multi = jax.vmap(fanout, in_axes=profile_axes)
-    return jax.vmap(multi, in_axes=(0,) * 8)
+    return greedy_scan
 
 
 @functools.lru_cache(maxsize=1)
@@ -460,8 +519,8 @@ def _lp_sweep(jnp, lax, vs, table, padded_n):
     C = V * B
     lanes = 128 if C % 128 == 0 else C
     shape = (padded_n, C // lanes, lanes)
-    # step-major lanes: the transpose back lands in the layout the
-    # vmapped scan reads its inputs in, with no second copy
+    # step-major lanes: the transpose back lands in the layout the scan
+    # reads its inputs in, with no second copy
     own = lax.broadcasted_iota(jnp.int32, shape, 0) \
         == vs.T.reshape(1, C // lanes, lanes)
     neg = jnp.int32(NEG_PATH)
@@ -486,30 +545,26 @@ def _blocked_impl():
     import jax.numpy as jnp
     from jax import lax
 
-    def chunk_scan(dur, work, rem, mask, est, lst, start, vs, rows, cols):
-        core = _placement_step(jnp, dur, work)
-
-        def step(state, xs):
-            return core(state, *xs), None
-
-        state, _ = lax.scan(step, (rem, mask, est, lst, start),
-                            (vs, rows, cols))
-        return state
-
-    # per-argument axes: (dur, work, rem, mask, est, lst, start, vs, rows,
-    # cols); unlike the dense scan, est/lst are per-row STATE here (they
-    # diverge across variants and profiles between chunk launches)
-    variant_axes = (None, None, 0, 0, 0, 0, 0, 0, 0, 0)
-    profile_axes = (None, None, 0, 0, 0, 0, 0, None, None, None)
-    multi = jax.vmap(jax.vmap(chunk_scan, in_axes=variant_axes),
-                     in_axes=profile_axes)
-
     def chunk(dur, work, rem, mask, est, lst, start, vs, forward,
               backward):
+        P, V, Tp = rem.shape
         Np = dur.shape[0]
+        R = P * V
         rows = _lp_sweep(jnp, lax, vs, forward, Np)
         cols = _lp_sweep(jnp, lax, vs, backward, Np)
-        return multi(dur, work, rem, mask, est, lst, start, vs, rows, cols)
+        core = _placement_step(jnp, lax)
+
+        def step(state, xs):
+            x, row, col = xs
+            return core(state, x, row[None], col[None])
+
+        state = (rem.reshape(R, Tp), mask.reshape(R, Tp + 1),
+                 est.reshape(R, Np), lst.reshape(R, Np))
+        xs = (_step_inputs(jnp, dur[None], work[None], vs[None], P),
+              rows.swapaxes(0, 1), cols.swapaxes(0, 1))
+        state, s = lax.scan(step, state, xs)
+        start = _place_starts(jnp, start.reshape(R, Np), vs[None], s)
+        return tuple(x.reshape(P, V, -1) for x in (*state, start))
 
     # donate the state buffers so chained chunk launches reuse device
     # memory (the state comes back with the same shapes; on CPU donation

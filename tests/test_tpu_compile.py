@@ -98,6 +98,18 @@ def test_greedy_grid_compiles(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+def _chunk_args(one_chip, n, t, steps, levels, edges):
+    """The blocked chunk program's arguments: the donated [P, V, .] state,
+    the chunk's task ids and the two level tables."""
+    i32 = jnp.int32
+    state = (_spec(one_chip, (PROFILES, COMBOS, t), i32),            # rem
+             _spec(one_chip, (PROFILES, COMBOS, t + 1), jnp.bool_),  # mask
+             *(_spec(one_chip, (PROFILES, COMBOS, n), i32),) * 3)
+    table = (_spec(one_chip, (levels, edges), i32),) * 3
+    return (_spec(one_chip, (n,), i32), _spec(one_chip, (n,), i32), *state,
+            _spec(one_chip, (COMBOS, steps), i32), table, table)
+
+
 def test_blocked_chunk_program_compiles(one_chip, monkeypatch):
     """The blocked form's chunk program, the device sweeps of a chunk's lp
     rows and columns and then its scan, at a small bucket: 1024 tasks, 32
@@ -109,14 +121,9 @@ def test_blocked_chunk_program_compiles(one_chip, monkeypatch):
     # CPU here: steer it to the compiled kernel the chip would take
     monkeypatch.setattr(maxplus_sweep, "resolve_mode", lambda _: "pallas")
 
-    n, t, steps, levels, edges, i32 = 1024, 256, 64, 32, 128, jnp.int32
-    state = (_spec(one_chip, (PROFILES, COMBOS, t), i32),            # rem
-             _spec(one_chip, (PROFILES, COMBOS, t + 1), jnp.bool_),  # mask
-             *(_spec(one_chip, (PROFILES, COMBOS, n), i32),) * 3)
-    table = (_spec(one_chip, (levels, edges), i32),) * 3
+    n, t, steps, levels, edges = 1024, 256, 64, 32, 128
     compiled = _blocked_impl().lower(
-        _spec(one_chip, (n,), i32), _spec(one_chip, (n,), i32), *state,
-        _spec(one_chip, (COMBOS, steps), i32), table, table).compile()
+        *_chunk_args(one_chip, n, t, steps, levels, edges)).compile()
     hlo = compiled.as_text()
     # one Mosaic kernel a sweep: the rows', then the columns'
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2
@@ -124,6 +131,39 @@ def test_blocked_chunk_program_compiles(one_chip, monkeypatch):
     # orders x 64 steps x 1024 tasks x 4 bytes, plus the sweep state
     lp = 2 * COMBOS * steps * n * 4
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * lp
+
+
+@pytest.mark.parametrize("program", ["dense-1k", "chunk-4k"])
+def test_greedy_scan_carry_is_lane_dense(one_chip, monkeypatch, program):
+    """The greedy scan's est/lst carry is one [rows, tasks] array each,
+    tasks minor (on the lanes), in the while loop's state: the dense grid
+    at the 1k bucket (one instance) and the blocked chunk program at the
+    4k bucket (chunks of 128 steps, tables 64 levels x 448 edges)."""
+    from repro.core.greedy_jax import _blocked_impl, _impl
+    from repro.kernels import maxplus_sweep
+
+    monkeypatch.setattr(maxplus_sweep, "resolve_mode", lambda _: "pallas")
+    i32, rows = jnp.int32, PROFILES * COMBOS
+    if program == "dense-1k":
+        n, t = N_BUCKET_1K, T_BUCKET_1K
+        args = (_spec(one_chip, (1, n), i32), _spec(one_chip, (1, n), i32),
+                _spec(one_chip, (1, n, n), i32),
+                _spec(one_chip, (1, PROFILES, t), i32),
+                _spec(one_chip, (1, PROFILES, COMBOS, t + 1), jnp.bool_),
+                _spec(one_chip, (1, n), i32), _spec(one_chip, (1, n), i32),
+                _spec(one_chip, (1, COMBOS, n), i32))
+        compiled = _impl().lower(*args).compile()
+    else:
+        n = N_BUCKET_4K
+        compiled = _blocked_impl().lower(
+            *_chunk_args(one_chip, n, T_BUCKET_4K, 128, 64, 448)).compile()
+    states = re.findall(r"= \((.*)\) while\(", compiled.as_text())
+    assert states, "no while loop"
+    layouts = [lay for state in states for lay in
+               re.findall(rf"s32\[{rows},{n}\]\{{([0-9,]+)", state)]
+    # est and lst, each with the task axis minor
+    assert len(layouts) >= 2, states
+    assert all(lay == "1,0" for lay in layouts), layouts
 
 
 @pytest.mark.parametrize("padded,n,t", [(False, N_BUCKET_1K, T_BUCKET_1K),
